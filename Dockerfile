@@ -25,6 +25,9 @@ FROM python:3.12-slim
 
 COPY --from=build /usr/local/lib/python3.12/site-packages /usr/local/lib/python3.12/site-packages
 COPY --from=build /src/api_ratelimit_tpu /app/api_ratelimit_tpu
+# the codec build is keyed by its source's hash (ops/native.py), so the
+# source travels with the .so
+COPY --from=build /src/native /app/native
 
 WORKDIR /app
 ENV PYTHONUNBUFFERED=1

@@ -165,13 +165,7 @@ def _map_ring(buf, slots: int, arena_rows: int):
 def _untrack_attached(shm) -> None:
     """3.12+ registers ATTACHED segments with the resource tracker too,
     and a tracker unlinking a segment the producer still serves would
-    tear the ring down under live traffic — undo that. On 3.10/3.11
-    attaching never registers, and unregistering an unknown name makes
-    the tracker process traceback, so this is version-gated."""
-    import sys
-
-    if sys.version_info < (3, 12):
-        return
+    tear the ring down under live traffic — undo that."""
     try:
         from multiprocessing import resource_tracker
 

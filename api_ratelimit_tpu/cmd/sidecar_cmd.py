@@ -145,9 +145,9 @@ def main(argv=None) -> None:
             )
         )
 
-    from ..utils.jaxsetup import respect_jax_platforms_env
+    from ..utils.jaxsetup import enable_compile_cache, serving_mesh
 
-    respect_jax_platforms_env()
+    logger.info("jax compile cache: %s", enable_compile_cache())
 
     # Surface the native codec state before the engine builds (runner.py
     # rationale): the device owner's pack/scatter hot path must not ride
@@ -184,14 +184,7 @@ def main(argv=None) -> None:
         device_count=len(_devices),
     )
 
-    mesh = None
-    if settings.tpu_mesh_devices > 1:
-        import jax
-        import numpy as np
-        from jax.sharding import Mesh
-
-        devices = jax.devices()[: settings.tpu_mesh_devices]
-        mesh = Mesh(np.array(devices), ("shard",))
+    mesh = serving_mesh(settings.tpu_mesh_devices)
 
     # FAULT_INJECT chaos hook (sites sidecar.server.submit +
     # batcher.submit): lets staging rehearse slow-engine / error-reply /

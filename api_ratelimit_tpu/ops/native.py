@@ -17,6 +17,7 @@ with g++ — no pip, no pybind11, just the baked-in toolchain.
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import logging
 import os
 import subprocess
@@ -34,8 +35,23 @@ _SRC = os.path.join(
 _OUT_DIR = os.path.join(
     os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "_native"
 )
+
+
+def _source_tag() -> str:
+    """First 16 hex digits of the source's sha256 ("nosrc" without it)."""
+    try:
+        with open(_SRC, "rb") as f:
+            return hashlib.sha256(f.read()).hexdigest()[:16]
+    except OSError:
+        return "nosrc"
+
+
+# keyed by the source's content, not by mtime: a build copied along with
+# an edited checkout (or restored with fresh timestamps) never matches a
+# source it was not built from, so a stale .so is never loaded
 _SO_PATH = os.environ.get(
-    "RL_NATIVE_LIB", os.path.join(_OUT_DIR, "libratelimit_host.so")
+    "RL_NATIVE_LIB",
+    os.path.join(_OUT_DIR, f"libratelimit_host-{_source_tag()}.so"),
 )
 
 _lock = threading.Lock()
@@ -80,19 +96,14 @@ def _configure(lib: ctypes.CDLL) -> ctypes.CDLL:
 
 
 def ensure_built() -> bool:
-    """Compile the shared object if it is missing or older than its source.
-    Best-effort and safe to call repeatedly/concurrently: builds go to a
-    per-pid temp path then atomically rename into place, and every failure
-    mode (no toolchain, read-only install, ...) returns False so callers
-    fall back to the Python path."""
+    """Compile the shared object unless the build for this exact source
+    exists. Best-effort and safe to call repeatedly/concurrently: builds go
+    to a per-pid temp path then atomically rename into place, and every
+    failure mode (no toolchain, read-only install, ...) returns False so
+    callers fall back to the Python path."""
     try:
-        if not os.path.exists(_SRC):
+        if os.path.exists(_SO_PATH) or not os.path.exists(_SRC):
             return os.path.exists(_SO_PATH)
-        if (
-            os.path.exists(_SO_PATH)
-            and os.path.getmtime(_SO_PATH) >= os.path.getmtime(_SRC)
-        ):
-            return True  # up to date; stale .so rebuilds below
         os.makedirs(_OUT_DIR, exist_ok=True)
         tmp = f"{_SO_PATH}.{os.getpid()}.tmp"
         subprocess.run(

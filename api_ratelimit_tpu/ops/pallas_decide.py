@@ -30,6 +30,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .decide import CODE_OK, CODE_OVER_LIMIT, DecideResult, floor_div_exact_i32
+from .pallas_slab import out_vma
 
 LANES = 128
 BLOCK_ROWS = 64  # 64 x 128 = 8192 items per grid step
@@ -147,7 +148,11 @@ def pallas_decide(
 
     # with scalar prefetch, the index map receives (grid_idx, *scalar_refs)
     block = pl.BlockSpec((block_rows, LANES), lambda i, *_: (i, 0))
-    out_shapes = [jax.ShapeDtypeStruct(shape2d, jnp.int32)] * 6
+    out_shapes = [
+        jax.ShapeDtypeStruct(
+            shape2d, jnp.int32, vma=out_vma(now, near_ratio, *inputs)
+        )
+    ] * 6
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,

@@ -61,6 +61,13 @@ LANES = 128
 BLOCK_ROWS = 256
 
 
+def out_vma(*xs) -> frozenset:
+    """The mesh axes a kernel's outputs vary over: the union of its
+    inputs'. Under shard_map (the compact mesh arm) pallas_call cannot
+    infer it and must be told; outside one it is empty."""
+    return frozenset().union(*(jax.typeof(x).vma for x in xs))
+
+
 def _masked_roll(x, k: int, axis: int, identity):
     """rolled[i] = x[i-k] along axis, with the first k positions set to
     identity — the shift step of a Hillis-Steele inclusive scan."""
@@ -346,13 +353,7 @@ def pallas_way_scan(
         out_specs=[block],
         scratch_shapes=[],
     )
-    (out,) = pl.pallas_call(
-        _way_scan_kernel,
-        grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((b, w), jnp.int32)],
-        interpret=interpret,
-    )(
-        now.astype(jnp.int32).reshape(1),
+    planes = [
         as_i32(st_fp_lo),
         as_i32(st_fp_hi),
         as_i32(st_count),
@@ -361,7 +362,15 @@ def pallas_way_scan(
         as_i32(st_div),
         q_lo,
         q_hi,
-    )
+    ]
+    (out,) = pl.pallas_call(
+        _way_scan_kernel,
+        grid_spec=grid_spec,
+        out_shape=[
+            jax.ShapeDtypeStruct((b, w), jnp.int32, vma=out_vma(now, *planes))
+        ],
+        interpret=interpret,
+    )(now.astype(jnp.int32).reshape(1), *planes)
     return out[:, 0], out[:, 1] > 0
 
 
@@ -432,7 +441,12 @@ def pallas_slab_apply(
             _slab_apply_kernel, decide=decide, lean=lean, block_rows=block_rows
         ),
         grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct(shape2d, jnp.int32)] * n_out,
+        out_shape=[
+            jax.ShapeDtypeStruct(
+                shape2d, jnp.int32, vma=out_vma(now, near_ratio, *inputs)
+            )
+        ]
+        * n_out,
         interpret=interpret,
     )(
         now.astype(jnp.int32).reshape(1),

@@ -45,29 +45,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-# jax.shard_map graduated from jax.experimental in newer releases; accept
-# either home so the mesh engine works across the toolchain versions this
-# repo meets (the baked image ships 0.4.x, where only the experimental
-# module exists). When neither is present, surface one clear error at
-# engine/step construction instead of an AttributeError mid-trace —
-# tests skip on `shard_map is None` with a reason rather than failing
-# collection.
-shard_map = getattr(jax, "shard_map", None)
-if shard_map is None:
-    try:
-        from jax.experimental.shard_map import shard_map
-    except ImportError:  # pragma: no cover - toolchain without shard_map
-        shard_map = None
-
-
-def _require_shard_map():
-    if shard_map is None:  # pragma: no cover - toolchain without shard_map
-        raise RuntimeError(
-            "this jax has neither jax.shard_map nor "
-            "jax.experimental.shard_map; the mesh-sharded slab engine "
-            "needs one of them (TPU_MESH_DEVICES must stay 0)"
-        )
-    return shard_map
 
 from ..ops.hashing import hot_slice_fp
 from ..ops.slab import (
@@ -195,7 +172,7 @@ def _sharded_body_after(
 
 def _build_step(mesh: Mesh, body, out_spec: P, **kw):
     axis = mesh.axis_names[0]
-    mapped = _require_shard_map()(
+    mapped = jax.shard_map(
         functools.partial(body, axis=axis, **kw),
         mesh=mesh,
         in_specs=(P(axis, None), P(None, None)),
@@ -302,7 +279,7 @@ def sharded_slab_step_after_compact(
     health[2]); state and blocks sharded on the leading axis, after sharded
     the same way (the host gathers and unscatters), health replicated."""
     axis = mesh.axis_names[0]
-    mapped = _require_shard_map()(
+    mapped = jax.shard_map(
         functools.partial(
             _sharded_body_after_compact,
             axis=axis,
@@ -445,8 +422,7 @@ class ShardedSlabEngine:
         if self._routed:
             # per-shard batching: one committed table per device instead
             # of a shard_map'd global array — routed launches are plain
-            # per-device jitted programs, so this arm works even on a
-            # toolchain without shard_map
+            # per-device jitted programs
             self._state = None
             self._tables = [
                 jax.device_put(
@@ -468,7 +444,7 @@ class ShardedSlabEngine:
             )
             axis_name = axis
             self._live_slots = jax.jit(
-                _require_shard_map()(
+                jax.shard_map(
                     lambda table, now: jax.lax.psum(
                         live_slot_count(table, now), axis_name
                     ),

@@ -104,15 +104,10 @@ def create_limiter(
     scope = stats_store.scope("ratelimit")
     if backend == "tpu":
         from .backends.tpu import TpuRateLimitCache
+        from .utils.jaxsetup import enable_compile_cache, serving_mesh
 
-        mesh = None
-        if settings.tpu_mesh_devices > 1:
-            import jax
-            from jax.sharding import Mesh
-            import numpy as np
-
-            devices = jax.devices()[: settings.tpu_mesh_devices]
-            mesh = Mesh(np.array(devices), ("shard",))
+        logger.info("jax compile cache: %s", enable_compile_cache())
+        mesh = serving_mesh(settings.tpu_mesh_devices)
         settings.warn_deprecated_knobs(logger)
         kwargs = {}
         ladder = settings.buckets()
@@ -206,6 +201,7 @@ class Runner:
         self.snapshotter = None
         self.lease_table = None
         self.federation = None
+        self.limiter: RateLimitCache | None = None
         self._ready = threading.Event()
 
     def get_stats_store(self) -> Store:
@@ -265,12 +261,6 @@ class Runner:
                 scope=self.scope.scope("journeys"),
             )
         journeys_mod.set_global_recorder(self.journeys)
-
-        # An explicitly pinned JAX_PLATFORMS (e.g. cpu for a host-only
-        # deployment) must beat any site-wide accelerator plugin override.
-        from .utils.jaxsetup import respect_jax_platforms_env
-
-        respect_jax_platforms_env()
 
         # Prewarm the native host codec here, at startup, for EVERY backend:
         # generate_cache_keys lazily triggers its build (a synchronous g++
@@ -456,7 +446,7 @@ class Runner:
                 lambda: json.dumps(self.federation.describe(), indent=2),
             )
 
-        cache = create_limiter(
+        cache = self.limiter = create_limiter(
             settings, base, self.stats_store, self.fault_injector,
             self.overload, self.lease_table,
         )

@@ -42,14 +42,7 @@ from api_ratelimit_tpu.ops.slab import (
     find_row_host,
 )
 from api_ratelimit_tpu.parallel import ShardedSlabEngine, make_mesh
-from api_ratelimit_tpu.parallel import sharded_slab as _sharded_slab
 from api_ratelimit_tpu.testing.oracle import VictimOracle
-
-pytestmark = pytest.mark.skipif(
-    _sharded_slab.shard_map is None,
-    reason="this jax has neither jax.shard_map nor "
-    "jax.experimental.shard_map",
-)
 
 N_DEV = 8
 SLOTS = N_DEV * 4096

@@ -253,3 +253,19 @@ class TestComposeKeysParity:
         big = "v" * 100_000
         got = native.compose_keys_batch([["d", "k", big]], [1234])
         assert got == [f"d_k_{big}_1234"]
+
+
+class TestBuildKey:
+    def test_so_is_named_by_source_hash(self):
+        """A build is found by the sha256 of the source it came from, never
+        by mtime: a stale .so copied beside edited sources is not loaded."""
+        import hashlib
+
+        if "RL_NATIVE_LIB" in os.environ:
+            pytest.skip("RL_NATIVE_LIB pins a build by path")
+        with open(native._SRC, "rb") as f:
+            digest = hashlib.sha256(f.read()).hexdigest()[:16]
+        assert os.path.basename(native._SO_PATH) == (
+            f"libratelimit_host-{digest}.so"
+        )
+        assert os.path.exists(native._SO_PATH)

@@ -20,15 +20,8 @@ from api_ratelimit_tpu.models import Code, Descriptor, RateLimitRequest, Unit
 from api_ratelimit_tpu.models.config import RateLimit, new_rate_limit_stats
 from api_ratelimit_tpu.models.response import RateLimitValue
 from api_ratelimit_tpu.parallel import ShardedSlabEngine, make_mesh
-from api_ratelimit_tpu.parallel import sharded_slab as _sharded_slab
 from api_ratelimit_tpu.stats import Store, TestSink
 from api_ratelimit_tpu.utils import FakeTimeSource
-
-pytestmark = pytest.mark.skipif(
-    _sharded_slab.shard_map is None,
-    reason="this jax has neither jax.shard_map nor "
-    "jax.experimental.shard_map",
-)
 
 
 def _fmix32(x: np.ndarray) -> np.ndarray:

@@ -73,3 +73,58 @@ def test_assertx_location():
     assert_(True, "fine")
     with pytest.raises(AssertionFailure, match="test_utils.py"):
         assert_(False, "boom")
+
+
+class TestJaxSetup:
+    """utils/jaxsetup.py: where the compile cache lives, and which devices
+    may serve."""
+
+    def test_compile_cache_env_wins(self, monkeypatch):
+        import jax
+
+        from api_ratelimit_tpu.utils import jaxsetup
+
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/somewhere/else")
+        before = jax.config.jax_compilation_cache_dir
+        assert jaxsetup.enable_compile_cache() == "/somewhere/else"
+        assert jax.config.jax_compilation_cache_dir == before  # untouched
+
+    def test_compile_cache_defaults_to_checkout(self, monkeypatch):
+        import os
+
+        import jax
+
+        from api_ratelimit_tpu.utils import jaxsetup
+
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        before = jax.config.jax_compilation_cache_dir
+        try:
+            path = jaxsetup.enable_compile_cache()
+            repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+            assert path == os.path.join(repo, ".jax_cache")
+            assert jax.config.jax_compilation_cache_dir == path
+        finally:
+            jax.config.update("jax_compilation_cache_dir", before)
+
+    def test_mesh_larger_than_visible_devices_raises(self):
+        import jax
+
+        from api_ratelimit_tpu.utils import jaxsetup
+
+        n = len(jax.devices())
+        assert jaxsetup.serving_mesh(0) is None
+        assert jaxsetup.serving_mesh(n).devices.size == n
+        with pytest.raises(RuntimeError, match=f"TPU_MESH_DEVICES={n + 1}"):
+            jaxsetup.serving_mesh(n + 1)
+
+    def test_host_devices_need_an_explicit_cpu_pin(self, monkeypatch):
+        """No TPU and no JAX_PLATFORMS=cpu: the engine must not quietly
+        serve from the host."""
+        from api_ratelimit_tpu.backends.tpu import SlabDeviceEngine
+        from api_ratelimit_tpu.utils import jaxsetup
+
+        monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+        with pytest.raises(RuntimeError, match="no TPU visible"):
+            jaxsetup.serving_devices()
+        with pytest.raises(RuntimeError, match="no TPU visible"):
+            SlabDeviceEngine(time_source=FakeTimeSource(0), n_slots=1 << 10)

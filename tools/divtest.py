@@ -34,9 +34,6 @@ def main() -> None:
     ap.add_argument("--repeats", type=int, default=8)
     args = ap.parse_args()
 
-    from api_ratelimit_tpu.utils.jaxsetup import respect_jax_platforms_env
-
-    respect_jax_platforms_env()
     import jax
     import jax.numpy as jnp
 
