@@ -41,14 +41,18 @@ device.
 Telemetry (scope `dispatch`): ring_wait_ms (publish -> take), pack_ms
 (frame gather into the padded operand, inside the launch callable's
 timing), launch_ms (async dispatch), redeem_ms (blocking readback +
-verdict scatter), batch_size, and queue_depth / inflight gauges on the
-stats-flush cadence.
+verdict scatter), submit_wait_ms (a caller's publish -> verdict),
+batch_size, and queue_depth / inflight gauges on the stats-flush cadence.
+
+Profiler spans (tracing/host.py, only while a capture runs): the owner
+loop is covered by ratelimit.dispatch.{wait_work, linger, take, launch,
+await_ready, redeem}, so every device gap falls inside one of them; a
+caller's wait is ratelimit.dispatch.submit_wait.
 """
 
 from __future__ import annotations
 
 import logging
-import os
 import threading
 import time
 from collections import deque
@@ -56,7 +60,7 @@ from collections import deque
 import numpy as np
 
 from ..limiter.cache import CacheError, DeadlineExceededError
-from ..tracing import SpanContext, active_span, global_tracer
+from ..tracing import SpanContext, active_span, global_tracer, host_span
 from ..tracing import journeys
 from ..utils.deadline import current_deadline
 from .overload import BrownoutError, QueueFullError
@@ -408,6 +412,7 @@ class DispatchLoop:
         self._ring_activity: dict = {}  # id(ring) -> [items_in, last_seq]
         self.deadline_drops = 0
         self._h_wait = self._h_batch = self._h_launch = self._h_redeem = None
+        self._h_submit_wait = None
         if scope is not None:
             from ..stats.store import DEFAULT_SIZE_BUCKETS
 
@@ -418,6 +423,7 @@ class DispatchLoop:
             )
             self._h_launch = ds.histogram("launch_ms")
             self._h_redeem = ds.histogram("redeem_ms")
+            self._h_submit_wait = ds.histogram("submit_wait_ms")
             ds.add_stat_generator(DispatchStats(self, ds))
         try:
             from ..ops import native
@@ -425,11 +431,6 @@ class DispatchLoop:
             self._scatter = native.scatter_rows if native.available() else None
         except Exception:  # noqa: BLE001 - codec is strictly optional
             self._scatter = None
-        # owner-thread profiling hook (tools/hotpath_profile.py --dispatch):
-        # the loop body runs under cProfile and the stats are kept on the
-        # instance for the tool to print after close()
-        self._profile = None
-        self._want_profile = os.environ.get("DISPATCH_PROFILE", "") == "1"
         self._thread = threading.Thread(
             target=self._loop, name="tpu-dispatch-owner", daemon=True
         )
@@ -564,9 +565,16 @@ class DispatchLoop:
         ring.publish(
             block, count, deadline, time.monotonic(), ticket, owned, ctx
         )
-        self._idle.clear()
-        self._work.set()
-        out = ticket.redeem()
+        h_wait = self._h_submit_wait
+        t_pub = time.perf_counter() if h_wait is not None else 0.0
+        with host_span("ratelimit.dispatch.submit_wait"):
+            self._idle.clear()
+            self._work.set()
+            try:
+                out = ticket.redeem()
+            finally:
+                if h_wait is not None:
+                    h_wait.record((time.perf_counter() - t_pub) * 1e3)
         stages = ticket.stage_ns
         if stages is not None:
             journeys.merge_owner_stages(stages)
@@ -652,11 +660,6 @@ class DispatchLoop:
     # -- owner thread --
 
     def _loop(self) -> None:
-        if self._want_profile:
-            import cProfile
-
-            self._profile = cProfile.Profile()
-            self._profile.enable()
         try:
             self._run()
         except BaseException as e:  # noqa: BLE001 - last-ditch safety net
@@ -665,9 +668,6 @@ class DispatchLoop:
             # submits, loudly
             logger.exception("dispatch owner thread died: %s", e)
             self._abort(CacheError(f"dispatch owner thread died: {e}"))
-        finally:
-            if self._profile is not None:
-                self._profile.disable()
 
     def _abort(self, exc: BaseException) -> None:
         self._close_rings()
@@ -741,8 +741,10 @@ class DispatchLoop:
                 # batcher's measured lull-cutoff win, PERF.md round 6).
                 # With a batch in flight, its execute time IS the
                 # coalescing window — take immediately.
-                self._linger()
-            frames, pending_free, expired, t_take = self._take()
+                with host_span("ratelimit.dispatch.linger"):
+                    self._linger()
+            with host_span("ratelimit.dispatch.take"):
+                frames, pending_free, expired, t_take = self._take()
             if expired:
                 self.deadline_drops += len(expired)
                 if self._overload is not None:
@@ -759,7 +761,8 @@ class DispatchLoop:
                 n_items = sum(count for _, count, _, _ in frames)
                 if self._h_batch is not None:
                     self._h_batch.record(n_items)
-                launched = self._launch_frames(frames, pending_free, t_take)
+                with host_span("ratelimit.dispatch.launch"):
+                    launched = self._launch_frames(frames, pending_free, t_take)
                 if launched is not None:
                     inflight.append(launched)
             elif pending_free:
@@ -778,13 +781,16 @@ class DispatchLoop:
                     # readiness polls would only add their granularity)
                     and sum(len(f[1]) for f in inflight)
                     < self._expect_frames
-                    and not self._await_work_or_ready(inflight[0][0])
                 ):
-                    # work arrived while the device was still executing:
-                    # launch it FIRST (the double-buffer overlap), redeem
-                    # after
-                    continue
-                self._redeem(*inflight.popleft())
+                    with host_span("ratelimit.dispatch.await_ready"):
+                        ready = self._await_work_or_ready(inflight[0][0])
+                    if not ready:
+                        # work arrived while the device was still
+                        # executing: launch it FIRST (the double-buffer
+                        # overlap), redeem after
+                        continue
+                with host_span("ratelimit.dispatch.redeem"):
+                    self._redeem(*inflight.popleft())
                 self._inflight_count = len(inflight)
                 continue
             if frames:
@@ -804,7 +810,8 @@ class DispatchLoop:
             # last take and the clear
             if self.queue_depth:
                 continue
-            self._wait_work(0.05)
+            with host_span("ratelimit.dispatch.wait_work"):
+                self._wait_work(0.05)
 
     def _pending_frames(self) -> int:
         return sum(r.tail - r.head for r in self._rings if not r.dead)

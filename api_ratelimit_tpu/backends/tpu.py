@@ -28,6 +28,7 @@ variant (hash-sharded slab, decisions combined over ICI) behind `mesh=`.
 from __future__ import annotations
 
 import collections
+import contextlib
 import dataclasses
 import logging
 import threading
@@ -75,7 +76,7 @@ from ..ops.slab import (
     default_ways,
     validate_ways,
 )
-from ..tracing import tag_do_limit_start
+from ..tracing import host_span, install_gc_spans, tag_do_limit_start
 from .batcher import MicroBatcher
 from .lease import LeaseOps, LeaseRegistry, apply_lease_ops
 
@@ -185,7 +186,8 @@ class SlabDeviceEngine:
         scope: optional stats Scope rooted at the service prefix (e.g.
         the runner's `ratelimit` scope). When set, the engine records the
         per-stage device histograms — <scope>.device.{pack_ms,launch_ms,
-        readback_ms} — and hands <scope>.batcher to the micro-batcher for
+        readback_ms}, <scope>.slab.{lock_wait_ms,health_drain_ms} — and
+        hands <scope>.batcher to the micro-batcher for
         queue-wait/batch-size/depth telemetry. None (the default) keeps
         the hot path entirely free of stats work.
 
@@ -375,13 +377,20 @@ class SlabDeviceEngine:
         # the item-list executors for the wire-block ones; the batcher
         # machinery is shared.
         self._h_pack = self._h_launch = self._h_readback = None
+        # the launch path's wait for _state_lock, and each health drain
+        # (stats flush, or inline once 4,096 vectors are parked)
+        self._h_lock_wait = self._h_health_drain = None
         batcher_scope = None
         if scope is not None:
             device_scope = scope.scope("device")
             self._h_pack = device_scope.histogram("pack_ms")
             self._h_launch = device_scope.histogram("launch_ms")
             self._h_readback = device_scope.histogram("readback_ms")
+            slab_scope = scope.scope("slab")
+            self._h_lock_wait = slab_scope.histogram("lock_wait_ms")
+            self._h_health_drain = slab_scope.histogram("health_drain_ms")
             batcher_scope = scope.scope("batcher")
+        install_gc_spans()
         # Every engine is block-native internally: the batcher's unit is a
         # uint32[6, n] row block and the executors copy whole column spans
         # into the padded device block — the in-process frontend rides the
@@ -463,10 +472,32 @@ class SlabDeviceEngine:
             self.precompile()
 
     def _drain_health_locked(self) -> None:
-        pending, self._pending_health = self._pending_health, []
-        for health in pending:
-            for i, v in enumerate(np.asarray(health)):
-                self._health_totals[i] += int(v)
+        """Fold the parked per-launch health vectors into the totals: one
+        blocking device read each. health_drain_ms takes one sample per
+        drain that found any parked (a flush after traffic stops finds
+        none, and would only dilute the mean)."""
+        with host_span("ratelimit.slab.health_drain"):
+            t0 = time.perf_counter()
+            pending, self._pending_health = self._pending_health, []
+            for health in pending:
+                for i, v in enumerate(np.asarray(health)):
+                    self._health_totals[i] += int(v)
+            if pending and self._h_health_drain is not None:
+                self._h_health_drain.record((time.perf_counter() - t0) * 1e3)
+
+    @contextlib.contextmanager
+    def _state_locked_for_launch(self):
+        """Hold _state_lock for the launch path, timing the wait for it
+        (the stats thread may hold it through a health drain)."""
+        with host_span("ratelimit.slab.lock_wait"):
+            t0 = time.perf_counter()
+            self._state_lock.acquire()
+            if self._h_lock_wait is not None:
+                self._h_lock_wait.record((time.perf_counter() - t0) * 1e3)
+        try:
+            yield
+        finally:
+            self._state_lock.release()
 
     def health_snapshot(self) -> dict:
         """Slab health for the stats tree (VERDICT round 1 weak #5): the two
@@ -484,7 +515,8 @@ class SlabDeviceEngine:
             return snap
         with self._state_lock:
             self._drain_health_locked()
-            live = int(slab_live_slots(self._state, now))
+            with host_span("ratelimit.slab.live_slots"):
+                live = int(slab_live_slots(self._state, now))
             snap = {
                 "evictions_expired": self._health_totals[HEALTH_EVICT_EXPIRED],
                 "evictions_window": self._health_totals[HEALTH_EVICT_WINDOW],
@@ -548,8 +580,8 @@ class SlabDeviceEngine:
             return self.precompiled
         # warm launches must not pollute the per-stage histograms: a
         # boot-time compile in launch_ms would own p99 forever
-        saved = self._h_pack, self._h_launch, self._h_readback
-        self._h_pack = self._h_launch = self._h_readback = None
+        saved = self._h_pack, self._h_launch, self._h_readback, self._h_lock_wait
+        self._h_pack = self._h_launch = self._h_readback = self._h_lock_wait = None
         try:
             for bucket in self._buckets:
                 packed = np.zeros((7, bucket), dtype=np.uint32)
@@ -561,7 +593,7 @@ class SlabDeviceEngine:
                     self._collect_array(self._dispatch_packed(packed, 0, cap))
                     self.precompiled[(bucket, name)] = True
         finally:
-            self._h_pack, self._h_launch, self._h_readback = saved
+            self._h_pack, self._h_launch, self._h_readback, self._h_lock_wait = saved
         return self.precompiled
 
     def profile_slab_split(
@@ -933,7 +965,7 @@ class SlabDeviceEngine:
             token = self._engine.launch_after_compact(packed, cap)
             # counted after the launch returns, like the single-device path:
             # a failed launch must not inflate the loss_ppm denominator
-            with self._state_lock:
+            with self._state_locked_for_launch():
                 self._decisions_total += n
             if self._h_launch is not None:
                 self._h_launch.record((time.perf_counter() - t_launch) * 1e3)
@@ -944,7 +976,7 @@ class SlabDeviceEngine:
             else jnp.uint16 if cap == 0xFFFF else jnp.uint32
         )
         use_pallas = self._use_pallas and not self._algos_seen
-        with self._state_lock:
+        with self._state_locked_for_launch():
             # promote injection rides BEFORE the step so a demoted key's
             # reappearing batch sees its restored counter in this very
             # launch (the tier's rows resume mid-window, not next-launch)
@@ -966,7 +998,8 @@ class SlabDeviceEngine:
             # demote drain OUTSIDE the state lock: the D2H wait on the
             # readback and the host-table inserts must not serialize the
             # next launch's dispatch
-            self._drain_victim(victim_rows)
+            with host_span("ratelimit.slab.victim_drain"):
+                self._drain_victim(victim_rows)
         if self._h_launch is not None:
             self._h_launch.record((time.perf_counter() - t_launch) * 1e3)
         return after_dev, n
@@ -1262,14 +1295,15 @@ class SlabDeviceEngine:
         """Blocking readback of one launch token. readback_ms covers the
         wait for device completion plus the D2H drain — the stage a slow
         link inflates (the co-located p99 estimate subtracts it)."""
-        t0 = time.perf_counter() if self._h_readback is not None else 0.0
-        payload, n = token
-        if self._engine is not None:
-            out = self._engine.collect_after_compact(payload)[:n]
-        else:
-            out = np.asarray(payload)[:n]
-        if self._h_readback is not None:
-            self._h_readback.record((time.perf_counter() - t0) * 1e3)
+        with host_span("ratelimit.device.readback"):
+            t0 = time.perf_counter() if self._h_readback is not None else 0.0
+            payload, n = token
+            if self._engine is not None:
+                out = self._engine.collect_after_compact(payload)[:n]
+            else:
+                out = np.asarray(payload)[:n]
+            if self._h_readback is not None:
+                self._h_readback.record((time.perf_counter() - t0) * 1e3)
         return out
 
     # -- block-native path (sidecar wire blocks; no per-item objects) --
@@ -1368,14 +1402,11 @@ class SlabDeviceEngine:
 
     def _execute_blocks_launch(self, blocks: list[np.ndarray]):
         try:
-            if self._h_pack is None:
-                return [
-                    self._dispatch_packed(packed, n, cap)
-                    for packed, n, cap in self._iter_block_chunks(blocks)
-                ]
-            t0 = time.perf_counter()
-            chunks = list(self._iter_block_chunks(blocks))
-            self._h_pack.record((time.perf_counter() - t0) * 1e3)
+            with host_span("ratelimit.device.pack"):
+                t0 = time.perf_counter() if self._h_pack is not None else 0.0
+                chunks = list(self._iter_block_chunks(blocks))
+                if self._h_pack is not None:
+                    self._h_pack.record((time.perf_counter() - t0) * 1e3)
             return [
                 self._dispatch_packed(packed, n, cap)
                 for packed, n, cap in chunks

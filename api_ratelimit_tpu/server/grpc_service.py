@@ -33,6 +33,7 @@ from ..backends.overload import OverloadError
 from ..limiter.cache import CacheError, DeadlineExceededError
 from ..pb import rls_grpc
 from ..service.ratelimit import RateLimitService, ServiceError
+from ..tracing import host_span
 from ..utils.deadline import deadline_scope
 from . import proto_adapter
 
@@ -59,7 +60,8 @@ class RateLimitServicerV3(rls_grpc.RateLimitServiceV3Servicer):
         self._deadline_propagation = bool(deadline_propagation)
         # transport.grpc_ms: handler wall time — proto conversion + the
         # service call. The gap against the service's own latency_ms is
-        # the transport (receive-stage) overhead.
+        # the transport (receive-stage) overhead. The same block is the
+        # ratelimit.service.transport.grpc profiler span.
         self._h_receive = (
             stats_scope.scope("transport").histogram("grpc_ms")
             if stats_scope is not None
@@ -68,22 +70,23 @@ class RateLimitServicerV3(rls_grpc.RateLimitServiceV3Servicer):
 
     def ShouldRateLimit(self, request, context):  # noqa: N802
         logger.debug("handling v3 should_rate_limit for domain %s", request.domain)
-        t0 = time.perf_counter() if self._h_receive is not None else 0.0
-        remaining = (
-            context.time_remaining() if self._deadline_propagation else None
-        )
-        try:
-            with deadline_scope(remaining):
-                internal = proto_adapter.request_from_v3(request)
-                overall, statuses, headers = self._service.should_rate_limit(
-                    internal
-                )
-                return proto_adapter.response_to_v3(overall, statuses, headers)
-        except (CacheError, ServiceError) as e:
-            _abort_for(context, e)
-        finally:
-            if self._h_receive is not None:
-                self._h_receive.record((time.perf_counter() - t0) * 1e3)
+        with host_span("ratelimit.service.transport.grpc"):
+            t0 = time.perf_counter() if self._h_receive is not None else 0.0
+            remaining = (
+                context.time_remaining() if self._deadline_propagation else None
+            )
+            try:
+                with deadline_scope(remaining):
+                    internal = proto_adapter.request_from_v3(request)
+                    overall, statuses, headers = self._service.should_rate_limit(
+                        internal
+                    )
+                    return proto_adapter.response_to_v3(overall, statuses, headers)
+            except (CacheError, ServiceError) as e:
+                _abort_for(context, e)
+            finally:
+                if self._h_receive is not None:
+                    self._h_receive.record((time.perf_counter() - t0) * 1e3)
 
 
 class RateLimitServicerV2(rls_grpc.RateLimitServiceV2Servicer):

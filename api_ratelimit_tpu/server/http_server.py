@@ -151,7 +151,8 @@ def add_json_handler(
 ) -> None:
     """POST /json — HTTP/JSON mirror of the v3 RPC (server_impl.go:62-104).
     stats_scope (optional) records transport.json_ms: handler wall time —
-    body read + jsonpb conversion + the service call.
+    body read + jsonpb conversion + the service call; the same block is
+    the ratelimit.service.transport.json profiler span.
 
     deadline_propagation reads Envoy's x-envoy-expected-rq-timeout-ms
     request header (the HTTP twin of the gRPC deadline) and binds it via
@@ -177,13 +178,14 @@ def add_json_handler(
     def handle(h: _Handler) -> None:
         # HTTP middleware span honoring inbound B3 headers
         # (src/tracing/lightstep.go:107-160); no-op when tracing is off.
-        t0 = time.perf_counter() if h_receive is not None else 0.0
-        with tracing.start_http_server_span("/json", h.headers) as span:
-            with tracing.activate(span):
-                with deadline_scope(_remaining_seconds(h)):
-                    _handle_json(h)
-        if h_receive is not None:
-            h_receive.record((time.perf_counter() - t0) * 1e3)
+        with tracing.host_span("ratelimit.service.transport.json"):
+            t0 = time.perf_counter() if h_receive is not None else 0.0
+            with tracing.start_http_server_span("/json", h.headers) as span:
+                with tracing.activate(span):
+                    with deadline_scope(_remaining_seconds(h)):
+                        _handle_json(h)
+            if h_receive is not None:
+                h_receive.record((time.perf_counter() - t0) * 1e3)
 
     def _handle_json(h: _Handler) -> None:
         # A malformed Content-Length must be a 400, not a ValueError that
@@ -296,7 +298,9 @@ def new_debug_server(
     profile_dir (TPU_PROFILE_DIR): when set, GET /debug/profile?ms=N
     captures a jax.profiler device trace for N milliseconds into that
     directory — the on-demand view of what the dispatch owner loop keeps
-    the device doing. Empty leaves the endpoint mounted but disabled."""
+    the device doing, with the program's own ratelimit.* host spans
+    (tracing/host.py) on the same clock. Empty leaves the endpoint mounted
+    but disabled."""
     server = HttpServer(host, port, "debug")
 
     def handle_stats(h: _Handler) -> None:

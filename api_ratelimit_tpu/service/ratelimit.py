@@ -35,7 +35,7 @@ from ..limiter.cache import CacheError, DeadlineExceededError, RateLimitCache
 from ..models.config import ConfigError, RateLimit
 from ..models.descriptors import RateLimitRequest
 from ..models.response import Code, DescriptorStatus, DoLimitResponse, HeaderValue
-from ..tracing import active_span
+from ..tracing import active_span, host_span
 from ..tracing import journeys
 from ..utils import deadline as request_deadline
 from ..utils.sampler import BurstSampler, RandomSampler, Sampler
@@ -364,11 +364,12 @@ class RateLimitService:
             # descriptor yields the full precomputed record; `limits` is
             # only materialized on the cold paths that need it (shed /
             # fallback answers) — see _limits_of.
-            t0 = time.perf_counter()
-            resolve = compiled.resolve
-            domain = request.domain
-            resolved = [resolve(domain, d) for d in request.descriptors]
-            self._stats.matcher.record((time.perf_counter() - t0) * 1e3)
+            with host_span("ratelimit.service.host.matcher"):
+                t0 = time.perf_counter()
+                resolve = compiled.resolve
+                domain = request.domain
+                resolved = [resolve(domain, d) for d in request.descriptors]
+                self._stats.matcher.record((time.perf_counter() - t0) * 1e3)
             limits: list[RateLimit | None] | None = None
             for record in resolved:
                 if record is not None:

@@ -18,14 +18,21 @@ the Prometheus renderer (stats/prometheus.py -> GET /metrics on the debug
 port) instead of being shipped sample-by-sample. A request landing in the
 top (overflow) bucket may attach its trace id as an exemplar, linking the
 p99 tail straight to its span in /debug/traces.
+
+While a profiler capture runs (tracing/host.py), a flush is the
+ratelimit.stats.flush span and each generator inside it a
+ratelimit.stats.generate.<name> span (SlabHealthStats -> slab_health).
 """
 
 from __future__ import annotations
 
 import bisect
+import re
 import threading
 import time
 from typing import Protocol
+
+from ..tracing.host import host_span
 
 # Log-spaced (1-2.5-5 decades) millisecond boundaries covering 50us..2.5s —
 # chosen so the 2ms north-star p99 sits mid-ladder with resolution on both
@@ -383,7 +390,8 @@ class Store(Scope):
             generators = list(self._generators)
         for gen in generators:
             try:
-                gen.generate_stats()
+                with host_span(_generator_span_name(gen)):
+                    gen.generate_stats()
             except Exception:  # stats must never take the service down
                 pass
 
@@ -437,6 +445,10 @@ class Store(Scope):
     # -- flushing --
 
     def flush(self) -> None:
+        with host_span("ratelimit.stats.flush"):
+            self._flush()
+
+    def _flush(self) -> None:
         self._run_generators()
         with self._reg_lock:
             counters = list(self._counters.values())
@@ -482,6 +494,14 @@ class Store(Scope):
         if self._flush_thread is not None:
             self._flush_thread.join(timeout=1.0)
             self._flush_thread = None
+
+
+def _generator_span_name(gen) -> str:
+    """ratelimit.stats.generate.<the generator's class in snake case, less
+    its Stats suffix>: SlabHealthStats -> ...generate.slab_health."""
+    name = type(gen).__name__.lstrip("_")
+    name = name.removesuffix("Stats") or name
+    return "ratelimit.stats.generate." + re.sub(r"(?<!^)(?=[A-Z])", "_", name).lower()
 
 
 def new_null_store() -> Store:

@@ -7,8 +7,14 @@ See tracer.py for the design. Public surface:
     with tracer.start_span("op") as span, activate(span):
         ...
     span = active_span()                   # inside instrumented layers
+
+host.py puts the program's own spans on the JAX profiler's clock:
+
+    with host_span("ratelimit.dispatch.redeem"):   # no-op unless profiling
+        ...
 """
 
+from .host import host_span, install_gc_spans
 from .propagation import extract, inject
 from .tracer import (
     CollectorTracer,
@@ -47,7 +53,9 @@ __all__ = [
     "active_span",
     "extract",
     "global_tracer",
+    "host_span",
     "inject",
+    "install_gc_spans",
     "is_global_tracer_registered",
     "reset_global_tracer",
     "set_global_tracer",

@@ -82,3 +82,24 @@ def test_readme_drift_is_flagged(tmp_path):
     findings = lint.lint_readme(str(tmp_path), str(readme))
     assert len(findings) == 2
     assert all("ghost_name" in f for f in findings)
+
+
+def test_readme_span_names_resolve(tmp_path):
+    """A documented profiler span resolves to a literal host_span name;
+    a renamed span is flagged like a renamed stat."""
+    lint = _load_linter()
+    (tmp_path / "spans.py").write_text(
+        'with host_span("ratelimit.owner.take"):\n    pass\n'
+        "with host_span(\n    'ratelimit.owner.redeem'\n):\n    pass\n"
+    )
+    assert lint.span_names(str(tmp_path)) == {
+        "ratelimit.owner.take", "ratelimit.owner.redeem"}
+    readme = tmp_path / "README.md"
+    readme.write_text(
+        "| `ratelimit.owner.{take,redeem}` | spans |\n"
+        "| `ratelimit.owner.linger` | gone |\n"
+    )
+    findings = lint.lint_readme(str(tmp_path), str(readme))
+    assert len(findings) == 1 and "ratelimit.owner.linger" in findings[0]
+    # the package's own spans are found
+    assert "ratelimit.dispatch.submit_wait" in lint.span_names()

@@ -170,18 +170,6 @@ class TestHotpathProfile:
         assert any(ln.strip().startswith("shard_rows") for ln in lines)
         assert any("padding_waste_pct=" in ln for ln in lines)
 
-    def test_dispatch_arm_profiles_owner_thread(self):
-        proc = _run_tool(
-            "tools.hotpath_profile", ("-n", "120", "--top", "8", "--dispatch")
-        )
-        assert proc.returncode == 0, proc.stderr[-500:]
-        assert "path=dispatch-owner" in proc.stdout
-        lines = proc.stdout.splitlines()
-        header = [ln for ln in lines if "ncalls" in ln and "tottime" in ln]
-        assert header, "pstats table header missing"
-        # the profiled thread is the OWNER loop, not the request thread
-        assert any("dispatch.py" in ln and "_run" in ln for ln in lines)
-
     def test_frontend_arm_reports_native_split(self):
         """--frontend: one worker's decode→match→compose→publish loop
         over shm rings to a local owner, with the [native_split] line

@@ -8,15 +8,11 @@ cProfile and printed as a top-N cumulative table:
     python -m tools.hotpath_profile                 # 2000 requests, top 25
     python -m tools.hotpath_profile -n 500 --top 10 --sort tottime
     python -m tools.hotpath_profile --legacy        # pin the pre-vectorization path
-    python -m tools.hotpath_profile --dispatch      # profile the device-OWNER thread
     make profile
 
---dispatch profiles the dispatch loop's owner thread instead of the
-request thread: the loop runs its take/pack/launch/redeem cycle under its
-own cProfile (DISPATCH_PROFILE=1, backends/dispatch.py) while this thread
-drives traffic, and the owner's table is printed after close(). The
-`lock.acquire` line is the owner parked waiting for work/readbacks — the
-idle headroom; everything else is real per-cycle dispatch cost.
+The dispatch loop's owner thread is not profiled here: its
+take/launch/redeem cycle is named by ratelimit.dispatch.* spans in any
+jax.profiler capture (GET /debug/profile; tracing/host.py).
 
 Single-thread on purpose: cProfile instruments only the calling thread,
 so the dispatcher/device threads show up as one honest
@@ -55,12 +51,6 @@ def main(argv=None) -> int:
         "--legacy",
         action="store_true",
         help="pin the legacy per-object host path (the A/B arm)",
-    )
-    parser.add_argument(
-        "--dispatch",
-        action="store_true",
-        help="profile the dispatch loop's device-owner thread instead of "
-        "the request thread (DISPATCH_PROFILE=1)",
     )
     parser.add_argument(
         "--frontend",
@@ -104,10 +94,6 @@ def main(argv=None) -> int:
         return _run_shard_split(args)
     if args.frontend:
         return _run_frontend_profile(args)
-    if args.dispatch:
-        # must be set BEFORE the service (and its DispatchLoop thread)
-        # is built: the owner thread reads it once at startup
-        os.environ["DISPATCH_PROFILE"] = "1"
     import bench
 
     service, cache, _store = bench._build_service(
@@ -123,8 +109,6 @@ def main(argv=None) -> int:
 
     if args.slab_split:
         return _run_slab_split(cache, _store)
-    if args.dispatch:
-        return _run_dispatch_profile(service, cache, reqs, args)
     try:
         if args.pyinstrument:
             return _run_pyinstrument(service, reqs, args)
@@ -269,45 +253,6 @@ def _run_shard_split(args) -> int:
         f"  padding_waste_pct={snap['padding_waste_pct']} "
         f"hot_keys={snap['hot_tier']['keys']}"
     )
-    return 0
-
-
-def _run_dispatch_profile(service, cache, reqs, args) -> int:
-    """Drive traffic from a small thread pool (the owner loop only earns
-    its keep under concurrency) and print the OWNER thread's cProfile."""
-    from concurrent.futures import ThreadPoolExecutor
-
-    loop = getattr(cache.engine, "_dispatch", None)
-    if loop is None:
-        print(
-            "[hotpath] dispatch loop is not active (DISPATCH_LOOP off or "
-            "direct mode); nothing to profile",
-            file=sys.stderr,
-        )
-        cache.close()
-        return 2
-
-    def worker(tid: int) -> None:
-        my = reqs[tid::4]
-        for i in range(args.n // 4):
-            service.should_rate_limit(my[i % len(my)])
-
-    t0 = time.perf_counter()
-    with ThreadPoolExecutor(4) as ex:
-        list(ex.map(worker, range(4)))
-    elapsed = time.perf_counter() - t0
-    cache.close()  # stops the owner thread; its profile is final now
-    print(
-        f"[hotpath] rate={round(args.n / elapsed)}/s requests={args.n} "
-        f"path=dispatch-owner"
-    )
-    if loop._profile is None:
-        print("[hotpath] owner thread recorded no profile", file=sys.stderr)
-        return 2
-    out = io.StringIO()
-    stats = pstats.Stats(loop._profile, stream=out)
-    stats.sort_stats(args.sort).print_stats(args.top)
-    print(out.getvalue())
     return 0
 
 

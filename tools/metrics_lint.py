@@ -18,8 +18,9 @@ Dynamically composed names (f-strings, variables) are out of scope.
 It also drift-checks the README: every backticked ``ratelimit.*`` metric
 name mentioned in README.md (brace alternations like ``{steals,drops}``
 expanded; ``<placeholder>`` tokens skipped) must resolve to a literal
-registration in the source — a renamed or deleted stat must not leave a
-stale name in the operator docs.
+registration in the source, or be the literal name of a profiler span
+(``host_span("ratelimit....")``, tracing/host.py) — a renamed or deleted
+stat or span must not leave a stale name in the operator docs.
 
 Run standalone (``python tools/metrics_lint.py``; exit 1 on findings) or
 via the fast pytest wrapper in tests/test_metrics_lint.py, which is part
@@ -40,6 +41,8 @@ _REGISTRATION = re.compile(
     r"\.(?P<kind>counter|gauge|timer|histogram)\(\s*(?P<q>['\"])(?P<name>[^'\"]+)(?P=q)"
 )
 _NAME_OK = re.compile(r"^[a-z0-9_]+(\.[a-z0-9_]+)*$")
+# profiler span names are full dotted names, written whole at the site
+_SPAN = re.compile(r"host_span\(\s*(?P<q>['\"])(?P<name>ratelimit\.[^'\"]+)(?P=q)")
 
 # freecache parity names (limiter/local_cache.py): the reference exports
 # the Go library's camelCase counters verbatim so existing dashboards and
@@ -59,25 +62,38 @@ NAME_ALLOWLIST = frozenset(
 )
 
 
-def iter_registrations(package_dir: str = PACKAGE):
-    """Yield (name, kind, file, line) for every literal registration."""
+def _package_sources(package_dir: str):
+    """Yield (path, text) of every .py file under the package."""
     for dirpath, dirnames, filenames in os.walk(package_dir):
         dirnames[:] = [d for d in dirnames if d != "__pycache__"]
         for filename in sorted(filenames):
-            if not filename.endswith(".py"):
-                continue
-            path = os.path.join(dirpath, filename)
-            with open(path, encoding="utf-8") as f:
-                text = f.read()
-            # whole-file scan: \s* spans newlines, so a registration whose
-            # string literal sits on a continuation line still counts
-            for m in _REGISTRATION.finditer(text):
-                yield (
-                    m.group("name"),
-                    m.group("kind"),
-                    os.path.relpath(path, REPO),
-                    text.count("\n", 0, m.start()) + 1,
-                )
+            if filename.endswith(".py"):
+                path = os.path.join(dirpath, filename)
+                with open(path, encoding="utf-8") as f:
+                    yield path, f.read()
+
+
+def iter_registrations(package_dir: str = PACKAGE):
+    """Yield (name, kind, file, line) for every literal registration."""
+    for path, text in _package_sources(package_dir):
+        # whole-file scan: \s* spans newlines, so a registration whose
+        # string literal sits on a continuation line still counts
+        for m in _REGISTRATION.finditer(text):
+            yield (
+                m.group("name"),
+                m.group("kind"),
+                os.path.relpath(path, REPO),
+                text.count("\n", 0, m.start()) + 1,
+            )
+
+
+def span_names(package_dir: str = PACKAGE) -> set[str]:
+    """The literal names of the package's profiler spans."""
+    return {
+        m.group("name")
+        for _path, text in _package_sources(package_dir)
+        for m in _SPAN.finditer(text)
+    }
 
 
 def lint(package_dir: str = PACKAGE) -> list[str]:
@@ -143,16 +159,19 @@ def lint_readme(
 ) -> list[str]:
     """README drift check: every documented ratelimit.* metric must end in
     a literal stat name registered somewhere in the package (registrations
-    are scope-relative, so the check is a dotted-suffix match)."""
+    are scope-relative, so the check is a dotted-suffix match), or be a
+    profiler span's literal name."""
     findings: list[str] = []
     literals = {name for name, _, _, _ in iter_registrations(package_dir)}
+    spans = span_names(package_dir)
     for name in readme_metric_names(readme_path):
-        if not any(
+        if name not in spans and not any(
             name == lit or name.endswith("." + lit) for lit in literals
         ):
             findings.append(
                 f"README.md: metric {name!r} does not match any literal "
-                f"stat registration in the package (renamed or deleted?)"
+                f"stat registration or span in the package (renamed or "
+                f"deleted?)"
             )
     return findings
 
