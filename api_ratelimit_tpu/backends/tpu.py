@@ -65,8 +65,8 @@ from ..ops.slab import (
     HEALTH_EVICT_EXPIRED,
     HEALTH_EVICT_LIVE,
     HEALTH_EVICT_WINDOW,
-    HEALTH_WIDTH,
     ROW_WIDTH,
+    ParkedHealth,
     make_slab,
     slab_export_copy,
     slab_import_rows,
@@ -186,7 +186,8 @@ class SlabDeviceEngine:
         scope: optional stats Scope rooted at the service prefix (e.g.
         the runner's `ratelimit` scope). When set, the engine records the
         per-stage device histograms — <scope>.device.{pack_ms,launch_ms,
-        readback_ms}, <scope>.slab.{lock_wait_ms,health_drain_ms} — and
+        readback_ms}, <scope>.slab.{lock_wait_ms,health_drain_ms,
+        health_vectors} — and
         hands <scope>.batcher to the micro-batcher for
         queue-wait/batch-size/depth telemetry. None (the default) keeps
         the hot path entirely free of stats work.
@@ -344,12 +345,12 @@ class SlabDeviceEngine:
                 )
         # lossy-event counters (the eviction mix / in-batch contention
         # drops — ops/slab.py HEALTH_* layout): per-launch device health
-        # vectors are parked un-fetched (reading 16 bytes inline would add
-        # a D2H round trip to every launch) and drained on the stats-flush
-        # cadence. _state_lock serializes state rebinds (the steps donate
-        # their input state) against the occupancy read from the stats
-        # thread.
-        self._health_totals = [0] * HEALTH_WIDTH
+        # vectors are parked un-fetched and drained on the stats-flush
+        # cadence, fetched with _state_lock released (ops/slab.py
+        # ParkedHealth). _state_lock serializes state rebinds (the steps
+        # donate their input state) against the occupancy and sketch reads
+        # from the stats thread.
+        self._health = ParkedHealth()
         # decisions submitted to the device — the denominator that turns the
         # lossy-event counters into an alarmable RATE (VERDICT r4 weak #3:
         # absolute counts can triple silently; a ratio gauge cannot)
@@ -359,7 +360,6 @@ class SlabDeviceEngine:
         # bench chain-time the device program at the batch size the service
         # path really ran (the device/host p99 split, VERDICT r4 weak #4)
         self.launch_sizes: collections.deque = collections.deque(maxlen=4096)
-        self._pending_health: list = []
         self._state_lock = threading.Lock()
         # occupancy pressure watermark: a pure OBSERVABILITY threshold
         # driven on the health_snapshot cadence (_apply_watermarks) — it
@@ -378,10 +378,14 @@ class SlabDeviceEngine:
         # machinery is shared.
         self._h_pack = self._h_launch = self._h_readback = None
         # the launch path's wait for _state_lock, and each health drain
-        # (stats flush, or inline once 4,096 vectors are parked)
+        # (stats flush, or inline once 4,096 vectors are parked): its time
+        # and the vectors it folded
         self._h_lock_wait = self._h_health_drain = None
+        self._h_health_vectors = None
         batcher_scope = None
         if scope is not None:
+            from ..stats.store import DEFAULT_SIZE_BUCKETS
+
             device_scope = scope.scope("device")
             self._h_pack = device_scope.histogram("pack_ms")
             self._h_launch = device_scope.histogram("launch_ms")
@@ -389,6 +393,9 @@ class SlabDeviceEngine:
             slab_scope = scope.scope("slab")
             self._h_lock_wait = slab_scope.histogram("lock_wait_ms")
             self._h_health_drain = slab_scope.histogram("health_drain_ms")
+            self._h_health_vectors = slab_scope.histogram(
+                "health_vectors", boundaries=DEFAULT_SIZE_BUCKETS
+            )
             batcher_scope = scope.scope("batcher")
         install_gc_spans()
         # Every engine is block-native internally: the batcher's unit is a
@@ -471,24 +478,24 @@ class SlabDeviceEngine:
         if precompile:
             self.precompile()
 
-    def _drain_health_locked(self) -> None:
-        """Fold the parked per-launch health vectors into the totals: one
-        blocking device read each. health_drain_ms takes one sample per
-        drain that found any parked (a flush after traffic stops finds
-        none, and would only dilute the mean)."""
+    def _drain_health(self) -> list[int]:
+        """Fold the parked per-launch health vectors into the totals, with
+        _state_lock held for the list swap alone; returns the totals.
+        health_drain_ms and health_vectors take one sample per drain that
+        found any parked (a flush after traffic stops finds none, and
+        would only dilute the mean)."""
         with host_span("ratelimit.slab.health_drain"):
             t0 = time.perf_counter()
-            pending, self._pending_health = self._pending_health, []
-            for health in pending:
-                for i, v in enumerate(np.asarray(health)):
-                    self._health_totals[i] += int(v)
-            if pending and self._h_health_drain is not None:
+            n, totals = self._health.drain(self._state_lock)
+            if n and self._h_health_drain is not None:
                 self._h_health_drain.record((time.perf_counter() - t0) * 1e3)
+                self._h_health_vectors.record(n)
+        return totals
 
     @contextlib.contextmanager
     def _state_locked_for_launch(self):
         """Hold _state_lock for the launch path, timing the wait for it
-        (the stats thread may hold it through a health drain)."""
+        (the stats thread holds it for the occupancy and sketch reads)."""
         with host_span("ratelimit.slab.lock_wait"):
             t0 = time.perf_counter()
             self._state_lock.acquire()
@@ -513,20 +520,21 @@ class SlabDeviceEngine:
             snap["loss_ppm"] = _loss_ppm(snap)
             self._apply_watermarks(snap, now)
             return snap
+        totals = self._drain_health()
         with self._state_lock:
-            self._drain_health_locked()
             with host_span("ratelimit.slab.live_slots"):
                 live = int(slab_live_slots(self._state, now))
-            snap = {
-                "evictions_expired": self._health_totals[HEALTH_EVICT_EXPIRED],
-                "evictions_window": self._health_totals[HEALTH_EVICT_WINDOW],
-                "evictions_live": self._health_totals[HEALTH_EVICT_LIVE],
-                "drops": self._health_totals[HEALTH_DROPS],
-                "algo_resets": self._health_totals[HEALTH_ALGO_RESETS],
-                "decisions": self._decisions_total,
-                "live_slots": live,
-                "occupancy": live / self._n_slots,
-            }
+            decisions = self._decisions_total
+        snap = {
+            "evictions_expired": totals[HEALTH_EVICT_EXPIRED],
+            "evictions_window": totals[HEALTH_EVICT_WINDOW],
+            "evictions_live": totals[HEALTH_EVICT_LIVE],
+            "drops": totals[HEALTH_DROPS],
+            "algo_resets": totals[HEALTH_ALGO_RESETS],
+            "decisions": decisions,
+            "live_slots": live,
+            "occupancy": live / self._n_slots,
+        }
         snap["loss_ppm"] = _loss_ppm(snap)
         self._apply_watermarks(snap, now)
         return snap
@@ -566,15 +574,16 @@ class SlabDeviceEngine:
         bucket ladder can produce — each bucket size x each saturating
         readback dtype (u8/u16/u32) — BEFORE the first request, so a
         first-touch XLA compile (hundreds of ms to seconds) never rides a
-        caller's deadline. Each shape is warmed with an all-padding
-        (hits == 0) launch through the REAL donated-state chain: padding
-        lanes write nothing (ops/slab.py, the hits > 0 gates), so the slab
-        is bit-identical afterwards, and warming through the actual jit
-        call populates the dispatch cache the hot path hits (an AOT
-        lower().compile() object would compile the same program but leave
-        jit's own call cache cold). Returns the covered-shape map, also
-        kept as `precompiled`. The mesh engine owns its own program cache
-        and is skipped."""
+        caller's deadline (nor the health drain's fold, which runs last,
+        over the warm launches' vectors). Each shape is warmed with an
+        all-padding (hits == 0) launch through the REAL donated-state
+        chain: padding lanes write nothing (ops/slab.py, the hits > 0
+        gates), so the slab is bit-identical afterwards, and warming
+        through the actual jit call populates the dispatch cache the hot
+        path hits (an AOT lower().compile() object would compile the same
+        program but leave jit's own call cache cold). Returns the
+        covered-shape map, also kept as `precompiled`. The mesh engine
+        owns its own program cache and is skipped."""
         if self._engine is not None:
             _log.info("precompile: mesh engine manages its own programs")
             return self.precompiled
@@ -592,6 +601,7 @@ class SlabDeviceEngine:
                 ):
                     self._collect_array(self._dispatch_packed(packed, 0, cap))
                     self.precompiled[(bucket, name)] = True
+            self._health.drain(self._state_lock)
         finally:
             self._h_pack, self._h_launch, self._h_readback, self._h_lock_wait = saved
         return self.precompiled
@@ -990,10 +1000,10 @@ class SlabDeviceEngine:
             after_dev, health, victim_rows = self._step_after_locked(
                 packed, dtype, use_pallas
             )
-            self._pending_health.append(health)
+            self._health.park(health)
             self._decisions_total += n
-            if len(self._pending_health) > 4096:
-                self._drain_health_locked()
+        if self._health.full:
+            self._drain_health()
         if victim_rows is not None:
             # demote drain OUTSIDE the state lock: the D2H wait on the
             # readback and the host-table inserts must not serialize the

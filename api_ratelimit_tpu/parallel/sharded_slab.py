@@ -61,7 +61,6 @@ from ..ops.slab import (
     HEALTH_EVICT_EXPIRED,
     HEALTH_EVICT_LIVE,
     HEALTH_EVICT_WINDOW,
-    HEALTH_WIDTH,
     PACKED_OUT_ROWS,
     ROW_DIVIDER,
     ROW_FP_HI,
@@ -70,6 +69,7 @@ from ..ops.slab import (
     ROW_LIMIT,
     ROW_SCALARS,
     ROW_WIDTH,
+    ParkedHealth,
     SlabState,
     _slab_step_sorted,
     _slab_update_sorted,
@@ -454,12 +454,12 @@ class ShardedSlabEngine:
                 )
             )
         # cumulative mesh-wide health: the eviction mix + contention drops
-        # (ops/slab.py HEALTH_* layout)
-        self.health_totals = [0] * HEALTH_WIDTH
+        # (ops/slab.py HEALTH_* layout), parked per launch and drained with
+        # the state lock released (ops/slab.py ParkedHealth)
+        self._health = ParkedHealth()
         # Serializes state rebinds (donating steps) against the occupancy
         # read — without it the stats thread can hit a donated buffer.
         self._state_lock = threading.Lock()
-        self._pending_health: list = []
 
         # -- routing telemetry (both arms; shard_routing_snapshot) --
         self._launches = 0
@@ -566,7 +566,8 @@ class ShardedSlabEngine:
         packed_dev = jax.device_put(packed, self._batch_sharding)
         with self._state_lock:
             self._state, out, health = self._step(self._state, packed_dev)
-            self._note_health(health)
+            self._health.park(health)
+        self._drain_health_if_full()
         return np.asarray(out)
 
     def step_after(self, packed: np.ndarray, cap: int = 0xFFFFFFFF) -> np.ndarray:
@@ -584,7 +585,8 @@ class ShardedSlabEngine:
         packed_dev = jax.device_put(packed, self._batch_sharding)
         with self._state_lock:
             self._state, after, health = step(self._state, packed_dev)
-            self._note_health(health)
+            self._health.park(health)
+        self._drain_health_if_full()
         return np.asarray(after)
 
     def step_after_compact(self, packed: np.ndarray, cap: int = 0xFFFFFFFF) -> np.ndarray:
@@ -680,10 +682,11 @@ class ShardedSlabEngine:
         blocks_dev = jax.device_put(blocks, self._blocks_sharding)
         with self._state_lock:
             self._state, after_blocks, health = step(self._state, blocks_dev)
-            self._note_health(health)
+            self._health.park(health)
             self._note_routing_locked(
                 counts, n_dev * bucket, t0, t1, t2, time.perf_counter_ns()
             )
+        self._drain_health_if_full()
         return (after_blocks, routed_idx, routed_owner, within, b, hot_remap)
 
     def _launch_routed(
@@ -740,12 +743,13 @@ class ShardedSlabEngine:
                 table, after, health = step(self._tables[d], blk)
                 self._tables[d] = table
                 afters[d] = after
-                self._note_health(health)
+                self._health.park(health)
             self._note_routing_locked(
                 counts,
                 sum(blk.shape[1] for blk in blocks.values()),
                 t0, t1, t2, time.perf_counter_ns(),
             )
+        self._drain_health_if_full()
         return {
             "mode": "routed",
             "afters": afters,
@@ -1178,19 +1182,15 @@ class ShardedSlabEngine:
             else:
                 self._state = jax.device_put(full, self._state_sharding)
 
-    def _note_health(self, health) -> None:
-        """Defer the tiny health readback off the hot path: park the device
-        array; drain when the stats flush asks (the launches are long done
-        by then, so asarray is a copy, not a sync)."""
-        self._pending_health.append(health)
-        if len(self._pending_health) > 4096:
-            self._drain_health_locked()
+    @property
+    def health_totals(self) -> list[int]:
+        return self._health.totals
 
-    def _drain_health_locked(self) -> None:
-        pending, self._pending_health = self._pending_health, []
-        for health in pending:
-            for i, v in enumerate(np.asarray(health)):
-                self.health_totals[i] += int(v)
+    def _drain_health_if_full(self) -> None:
+        """The launch path's inline drain, after it released _state_lock:
+        only once the stats flush has left more than INLINE parked."""
+        if self._health.full:
+            self._health.drain(self._state_lock)
 
     def health_snapshot(self, now: int | None = None) -> dict:
         """Cumulative mesh-wide lossy-event counters + live-slot occupancy
@@ -1201,23 +1201,23 @@ class ShardedSlabEngine:
             from ..utils.timeutil import process_time_source
 
             now = process_time_source().unix_now()
+        _, totals = self._health.drain(self._state_lock)
         with self._state_lock:
-            self._drain_health_locked()
             if self._routed:
                 live = sum(
                     int(self._live_one(t, now)) for t in self._tables
                 )
             else:
                 live = int(self._live_slots(self._state, now))
-            return {
-                "evictions_expired": self.health_totals[HEALTH_EVICT_EXPIRED],
-                "evictions_window": self.health_totals[HEALTH_EVICT_WINDOW],
-                "evictions_live": self.health_totals[HEALTH_EVICT_LIVE],
-                "drops": self.health_totals[HEALTH_DROPS],
-                "algo_resets": self.health_totals[HEALTH_ALGO_RESETS],
-                "live_slots": live,
-                "occupancy": live / self.n_slots_global,
-            }
+        return {
+            "evictions_expired": totals[HEALTH_EVICT_EXPIRED],
+            "evictions_window": totals[HEALTH_EVICT_WINDOW],
+            "evictions_live": totals[HEALTH_EVICT_LIVE],
+            "drops": totals[HEALTH_DROPS],
+            "algo_resets": totals[HEALTH_ALGO_RESETS],
+            "live_slots": live,
+            "occupancy": live / self.n_slots_global,
+        }
 
     # Matches TpuRateLimitCache._launch_packed's contract (rows 0..7, already
     # in arrival order) so the backend can swap engines transparently.

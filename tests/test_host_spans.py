@@ -18,7 +18,7 @@ import pytest
 
 from api_ratelimit_tpu.backends.dispatch import DispatchLoop
 from api_ratelimit_tpu.backends.tpu import SlabDeviceEngine
-from api_ratelimit_tpu.ops.slab import HEALTH_WIDTH
+from api_ratelimit_tpu.ops.slab import HEALTH_WIDTH, fold_health_vectors
 from api_ratelimit_tpu.stats import Store
 from api_ratelimit_tpu.tracing import host
 from api_ratelimit_tpu.tracing import host_span, install_gc_spans
@@ -145,10 +145,10 @@ def _hist(store, name):
     return int(s["count"]), float(s["sum"])
 
 
-def _engine(store):
+def _engine(store, n_slots=1 << 12):
     return SlabDeviceEngine(
         time_source=FakeTimeSource(1_700_000_000),
-        n_slots=1 << 12,
+        n_slots=n_slots,
         buckets=(128,),
         max_batch=128,
         use_pallas=False,
@@ -214,23 +214,45 @@ class TestEngineTimings:
             eng.health_snapshot()  # nothing parked: no drain to time
             assert _hist(store, "ratelimit.slab.health_drain_ms")[0] == 1
             # the inline drain once more than 4,096 vectors are parked
-            eng._pending_health.extend(
+            eng._health.pending.extend(
                 [np.zeros(HEALTH_WIDTH, np.uint32)] * 4096)
             eng.submit_block(_rows(8, 2))
             assert _hist(store, "ratelimit.slab.health_drain_ms")[0] == 2
-            assert eng._pending_health == []
+            assert eng._health.pending == []
+        finally:
+            eng.close()
+
+    def test_health_vectors_counts_each_drain(self):
+        """slab.health_vectors takes one sample per drain: the number of
+        vectors it folded."""
+        store = Store()
+        eng = _engine(store)
+        try:
+            for i in range(3):
+                eng.submit_block(_rows(8, i))
+            eng.health_snapshot()
+            assert _hist(store, "ratelimit.slab.health_vectors") == (1, 3.0)
+            eng.health_snapshot()  # nothing parked: no sample
+            assert _hist(store, "ratelimit.slab.health_vectors") == (1, 3.0)
+            eng._health.pending.extend(
+                [np.zeros(HEALTH_WIDTH, np.uint32)] * 4096)
+            eng.submit_block(_rows(8, 3))  # the inline drain: 4,096 + 1
+            assert _hist(store, "ratelimit.slab.health_vectors") == (2, 4100.0)
         finally:
             eng.close()
 
     def test_drain_and_lock_wait_spans_name_the_stall(self, tmp_path):
-        """In a capture, a launch held up by a health drain shows as
-        ratelimit.slab.lock_wait on the launching thread, overlapping
-        ratelimit.slab.health_drain on the draining one."""
+        """The stall is gone: in a capture, a launch made while a health
+        drain fetches its vectors waits for the state lock only briefly,
+        and its ratelimit.slab.lock_wait span ends before the drain's
+        ratelimit.slab.health_drain does."""
+        fetching = threading.Event()
 
         class SlowHealth:
             """A parked health vector whose device read takes 50 ms."""
 
             def __array__(self, dtype=None, copy=None):
+                fetching.set()
                 time.sleep(0.05)
                 return np.zeros(HEALTH_WIDTH, np.uint32)
 
@@ -240,10 +262,10 @@ class TestEngineTimings:
             eng.submit_block(_rows(8))
 
             def body():
-                eng._pending_health.append(SlowHealth())
+                eng._health.pending.extend(SlowHealth() for _ in range(3))
                 t = threading.Thread(target=eng.health_snapshot)
                 t.start()
-                time.sleep(0.01)
+                assert fetching.wait(10)
                 eng.submit_block(_rows(8, 3))
                 t.join(timeout=10)
                 assert not t.is_alive()
@@ -254,9 +276,148 @@ class TestEngineTimings:
         (wait,) = events["ratelimit.slab.lock_wait"]
         (drain,) = events["ratelimit.slab.health_drain"]
         assert drain[0] != wait[0]
-        assert wait[1] < drain[2] <= wait[2] and wait[2] - wait[1] >= 20e6
+        assert drain[1] < wait[1] and drain[2] - drain[1] >= 100e6
+        assert wait[2] - wait[1] <= 10e6 and wait[2] < drain[2]
         assert {"ratelimit.device.pack", "ratelimit.device.readback",
                 "ratelimit.slab.live_slots"} <= set(events)
+
+
+def _mesh_packed(n, seed, now):
+    rng = np.random.default_rng(seed)
+    p = np.zeros((7, n), dtype=np.uint32)
+    p[0] = rng.integers(1, 2**32, n, dtype=np.uint64)
+    p[1] = rng.integers(1, 2**32, n, dtype=np.uint64)
+    p[2], p[3], p[4] = 1, 1000, 60
+    p[6, 0] = now
+    p[6, 1] = np.float32(0.8).view(np.uint32)
+    p[6, 2] = np.float32(1.0).view(np.uint32)
+    return p
+
+
+class TestHealthDrainCounts:
+    @pytest.mark.parametrize("n", [1, 255, 256, 257, 1150])
+    def test_fold_matches_the_loop(self, n):
+        """fold_health_vectors against a Python loop over each vector:
+        device vectors on two devices (a group and a chunk chain each) and
+        host arrays, in one call."""
+        import jax
+
+        rng = np.random.default_rng(n)
+        host = rng.integers(0, 1 << 16, (n, HEALTH_WIDTH)).astype(np.uint32)
+        devices = jax.devices()[:2]
+        vectors = [jax.device_put(h, devices[i % 2]) if i % 3 else h
+                   for i, h in enumerate(host)]
+        expected = [0] * HEALTH_WIDTH
+        for h in host:
+            for k, v in enumerate(h):
+                expected[k] += int(v)
+        folded = fold_health_vectors(vectors)
+        assert folded.dtype == np.uint64 and folded.tolist() == expected
+
+    @pytest.mark.parametrize("engine,drain", [
+        ("single", "flush"), ("single", "inline"),
+        ("mesh", "flush"), ("mesh", "inline"),
+    ])
+    def test_every_vector_counted_once(self, monkeypatch, engine, drain):
+        """One thread launches while another snapshots over and over; the
+        final snapshot's totals are the sum of every parked vector, none
+        lost and none counted twice. The inline case parks 4,096 more
+        before some launches, so the launching thread drains them; a gate
+        keeps the snapshots out of that one step."""
+        from api_ratelimit_tpu.parallel import ShardedSlabEngine, make_mesh
+
+        now = 1_700_000_000
+        if engine == "single":
+            eng = _engine(Store(), n_slots=1 << 8)
+
+            def launch(i):
+                eng.submit_block(_rows(128, i))
+
+            def snapshot():
+                return eng.health_snapshot()
+        else:
+            eng = ShardedSlabEngine(mesh=make_mesh(), n_slots_global=8 * 64)
+
+            def launch(i):
+                eng.step_after_compact(_mesh_packed(128, i, now), 0xFFFF)
+
+            def snapshot():
+                return eng.health_snapshot(now)
+
+        parked = []
+        park = eng._health.park
+
+        def recording_park(health):
+            parked.append(health)
+            park(health)
+
+        monkeypatch.setattr(eng._health, "park", recording_park)
+        drained = []
+        drain_fn = eng._health.drain
+
+        def recording_drain(state_lock):
+            n, totals = drain_fn(state_lock)
+            drained.append((threading.current_thread().name, n))
+            return n, totals
+
+        monkeypatch.setattr(eng._health, "drain", recording_drain)
+        launch(0)  # the step and the drain's fold compile before the race
+        snapshot()
+        gate = threading.Lock()
+        done = threading.Event()
+        seen = []
+        keys = ("evictions_expired", "evictions_window", "evictions_live",
+                "drops", "algo_resets")
+
+        def snapshots():
+            while not done.is_set():
+                with gate:
+                    snap = snapshot()
+                seen.append([snap[k] for k in keys])
+                time.sleep(0.002)
+
+        t = threading.Thread(target=snapshots, name="snapshots")
+        switch = sys.getswitchinterval()
+        sys.setswitchinterval(1e-5)  # interleave the threads finely
+        t.start()
+        try:
+            for i in range(1, 41):
+                if drain == "inline" and i % 10 == 0:
+                    with gate:
+                        with eng._state_lock:
+                            for j in range(4096):
+                                eng._health.park(np.full(HEALTH_WIDTH, j % 7 + i,
+                                                         np.uint32))
+                        launch(i)
+                else:
+                    launch(i)
+                if i % 10 == 5:  # let a snapshot start after this launch
+                    n_seen = len(seen)
+                    deadline = time.monotonic() + 10
+                    while len(seen) < n_seen + 2 and time.monotonic() < deadline:
+                        time.sleep(0.001)
+        finally:
+            done.set()
+            t.join(timeout=30)
+            sys.setswitchinterval(switch)
+        assert not t.is_alive()
+        final = snapshot()
+        if engine == "single":
+            eng.close()
+        expected = [0] * HEALTH_WIDTH
+        for health in parked:
+            for k, v in enumerate(np.asarray(health)):
+                expected[k] += int(v)
+        assert [final[k] for k in keys] == expected
+        assert sum(expected[:4]) > 0  # the launches evicted or dropped
+        assert all(a <= b for prev, cur in zip(seen, seen[1:])
+                   for a, b in zip(prev, cur))
+        assert sum(n for _, n in drained) == len(parked)
+        by_snapshots = [n for name, n in drained if name == "snapshots" and n]
+        assert len(by_snapshots) >= 4
+        if drain == "inline":
+            assert len([n for name, n in drained
+                        if name != "snapshots" and n > 4096]) == 4
 
 
 class TestSubmitWait:

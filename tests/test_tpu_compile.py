@@ -153,3 +153,17 @@ def test_routed_shard_step(topo):
         _u32((SLOTS // 4, ROW_WIDTH), last), _u32((7, 8192), last)
     ).compile()
     _check(compiled, pallas=True)
+
+
+def test_health_fold(one_chip):
+    """The health drain's one on-device program: the first n of
+    HEALTH_FOLD parked vectors summed, whatever the drain's length."""
+    import jax
+    import jax.numpy as jnp
+
+    from api_ratelimit_tpu.ops.slab import HEALTH_FOLD, HEALTH_WIDTH, _fold_health
+
+    n = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    vector = _u32((HEALTH_WIDTH,), one_chip)
+    compiled = _fold_health.lower(n, *[vector] * HEALTH_FOLD).compile()
+    _check(compiled, pallas=False)
