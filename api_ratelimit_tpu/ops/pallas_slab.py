@@ -7,11 +7,14 @@ executed as ONE kernel pass over VMEM-resident tiles.
 
 Division of labor with XLA (ops/slab.py drives both):
 
-  XLA owns the data movement: the K-way probe gather, the 3-key sort that
-  groups duplicate keys, the stored-row gather, and the final row scatter.
-  Those compile to the TPU's native dynamic-gather/scatter paths, which a
-  hand-written kernel cannot beat — Pallas has no per-element HBM access;
-  it would have to emulate gathers with thousands of tiny DMAs.
+  XLA owns the gathers and the sort: the K-way probe gather, the 3-key
+  sort that groups duplicate keys, and the stored-row gather. Those
+  compile to the TPU's native dynamic-gather paths — Pallas has no
+  per-element HBM access; it would have to emulate gathers with thousands
+  of tiny DMAs. The final row write is the exception (the set-tile
+  write-back at the end of this module): XLA's scatter pays for every lane
+  of the launch, padding included, while a launch's writes land in a few
+  contiguous set tiles, one DMA each way.
 
   This kernel owns everything BETWEEN the gathers: the two segmented
   prefix scans (exclusive cumsum of hits; running max of segment bases)
@@ -454,3 +457,195 @@ def pallas_slab_apply(
         *inputs,
     )
     return tuple(o.reshape(b) for o in outs)
+
+
+# --- the set-tile write-back -------------------------------------------------
+#
+# The launch's one row write, for the ways == 128 shape. XLA's row scatter
+# costs ~70 ns per lane of the launch whether the lane writes or not
+# (padding carries the dropped index n), so a 65,536-wide launch paid for
+# every lane. Here the work follows the rows written: the batch is sorted
+# by slot = set * W + way, so the writes of one set are contiguous, and
+# the table's stored layout (u32[n_slots, 8]{0,1:T(8,128)}) makes each set
+# one contiguous (8, 128) tile — ROW_WIDTH sublanes by W ways. Per grid
+# step of WRITEBACK_CHUNK sorted lanes:
+#
+#   1. one DMA reads the tile of each set the chunk writes, all in flight
+#      at once (a (8, 1) column DMA per row would skip the read, but
+#      Mosaic refuses a DMA slice narrower than the 128-lane tiling);
+#   2. each written row is rotated into its way's lane of the tile, which
+#      rides in a register from lane to lane;
+#   3. one DMA writes each tile back.
+#
+# Lanes that write nothing (padding, contention losers, non-last
+# duplicates) are masked out of step 2, and grid steps past the last
+# non-padding lane do nothing. Step 2 is branch-free and unrolled
+# WRITEBACK_UNROLL lanes deep so that the rotates of neighbouring lanes
+# overlap: on one v5e this took the kernel from 2.35 to 1.36 ms at the
+# owner's launch (12,566 sets of 22,863 lanes; PERF.md). The unrolling is
+# left to the lowering (fori_loop unroll=True): a body traced once keeps
+# the kernel's share of a program's trace and lowering time small.
+
+WRITEBACK_CHUNK = 1024  # sorted lanes per grid step: 1024 tiles = 4 MiB VMEM
+WRITEBACK_UNROLL = 16
+WRITEBACK_WAIT_GROUP = 16  # tiles one DMA wait stands for
+
+
+def _writeback_kernel(
+    count_ref,  # SMEM int32[1]: lanes before the first padding lane
+    idx_ref,  # SMEM int32[chunk]: the slot each lane writes, n if none
+    rows_ref,  # VMEM uint32[chunk // 128, ROW_WIDTH, 128]: lane-major rows
+    _table_in,  # aliased to table_ref
+    table_ref,  # HBM uint32[n_sets, ROW_WIDTH, ways]
+    tiles_ref,  # VMEM uint32[chunk + 1, ROW_WIDTH, ways]: a tile per set, and a spare
+    sets_ref,  # SMEM int32[chunk]: the set each tile holds
+    sems,  # DMA semaphores: [0] reads, [1] writes
+    *,
+    chunk: int,
+    n_slots: int,
+):
+    way_bits = LANES.bit_length() - 1  # ways == LANES: a set is one lane row
+    n_sets = n_slots // LANES
+    spare = chunk  # the store target of lanes before the first tile opens
+    live = jnp.minimum(count_ref[0] - pl.program_id(0) * chunk, chunk)
+
+    def copy(k, read, size=None):
+        hbm = table_ref.at[sets_ref[k]] if size is None else table_ref.at[pl.ds(0, size)]
+        vmem = tiles_ref.at[k] if size is None else tiles_ref.at[pl.ds(0, size)]
+        if read:
+            return pltpu.make_async_copy(hbm, vmem, sems.at[0])
+        return pltpu.make_async_copy(vmem, hbm, sems.at[1])
+
+    def wait(n, read):
+        # a DMA semaphore counts what landed: wait for n tiles, `group` at
+        # a time and then one at a time (a chunk's tiles are distinct
+        # sets, so n <= n_sets)
+        group = 1 << (min(WRITEBACK_WAIT_GROUP, n_sets).bit_length() - 1)
+
+        def waits(size):
+            def body(i, c):
+                copy(0, read, size).wait()
+                return c
+
+            return body
+
+        jax.lax.fori_loop(0, n // group, waits(group), 0)
+        jax.lax.fori_loop(0, n % group, waits(None), 0)
+
+    @pl.when(live > 0)
+    def _():
+        def open_tile(j, carry):
+            k, cur = carry
+            slot = idx_ref[j]
+            s = slot >> way_bits
+            opens = (slot < n_slots) & (s != cur)
+
+            @pl.when(opens)
+            def _():
+                sets_ref[k] = s
+                copy(k, read=True).start()
+
+            return k + opens.astype(jnp.int32), jnp.where(opens, s, cur)
+
+        n_tiles, _ = jax.lax.fori_loop(
+            0, live, open_tile, (jnp.int32(0), jnp.int32(-1))
+        )
+        wait(n_tiles, read=True)
+
+        lane = jax.lax.broadcasted_iota(jnp.int32, tiles_ref.shape[1:], 1)
+
+        def merge(j, carry):
+            k, cur, tile = carry
+            slot = idx_ref[j]
+            written = (slot < n_slots) & (j < live)
+            s = slot >> way_bits
+            opens = written & (s != cur)
+            k = k + opens.astype(jnp.int32)
+            at = jnp.where(k > 0, k - 1, spare)
+            tile = jnp.where(opens, tiles_ref[at], tile)
+            way = slot & (LANES - 1)
+            # lane j % LANES of its block holds lane j's row: rotate it to `way`
+            row = pltpu.roll(rows_ref[j >> way_bits], (way - j) & (LANES - 1), axis=1)
+            tile = jnp.where((lane == way) & written, row, tile)
+            tiles_ref[at] = tile
+            return k, jnp.where(opens, s, cur), tile
+
+        def merge_block(t, carry):
+            # unroll=True: traced once, unrolled by the lowering
+            base = t * WRITEBACK_UNROLL
+            return jax.lax.fori_loop(
+                0, WRITEBACK_UNROLL, lambda u, c: merge(base + u, c), carry,
+                unroll=True,
+            )
+
+        jax.lax.fori_loop(
+            0, chunk // WRITEBACK_UNROLL, merge_block,
+            (jnp.int32(0), jnp.int32(-1), jnp.zeros(tiles_ref.shape[1:], tiles_ref.dtype)),
+        )
+
+        def write_tile(k, c):
+            copy(k, read=False).start()
+            return c
+
+        jax.lax.fori_loop(0, n_tiles, write_tile, 0)
+        # the next grid step may reopen this step's last set: land every
+        # write before it reads
+        wait(n_tiles, read=False)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def pallas_slab_writeback(
+    table: jnp.ndarray,  # uint32[n_slots, ROW_WIDTH]
+    write_idx: jnp.ndarray,  # int32[b] slot-sorted; n where the lane writes nothing
+    new_rows: jnp.ndarray,  # uint32[b, ROW_WIDTH] the rows, same order
+    count: jnp.ndarray,  # int32 scalar: lanes before the first padding lane
+    interpret: bool = False,
+):
+    """table.at[write_idx].set(new_rows, mode="drop") for a slot-sorted
+    launch on the ways == 128 geometry, in place (donate the table).
+    Lanes at or past `count` are never read; every written slot must be
+    unique (one writer per slot, as _finish_update guarantees).
+    Bit-identical to ops/slab.py _scatter_rows (tests/test_slab_writeback.py)."""
+    n, width = table.shape
+    (b,) = write_idx.shape
+    if b % LANES:
+        raise ValueError(f"batch size must be a multiple of {LANES}, got {b}")
+    n_sets = n // LANES
+    chunk = math.gcd(b, WRITEBACK_CHUNK)
+    # the stored layout's bitcast: one (ROW_WIDTH, ways) tile per set
+    sets = table.reshape(n_sets, LANES, width).transpose(0, 2, 1)
+    rows = new_rows.reshape(b // LANES, LANES, width).transpose(0, 2, 1)
+    count = jnp.asarray(count, jnp.int32).reshape(1)
+
+    def block(i, count_ref):
+        # chunks past the padding repeat the last live block: no refetch
+        return jnp.minimum(i, jnp.maximum(count_ref[0] - 1, 0) // chunk)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(b // chunk,),
+        in_specs=[
+            pl.BlockSpec((chunk,), lambda i, c: (block(i, c),),
+                         memory_space=pltpu.SMEM),
+            pl.BlockSpec((chunk // LANES, width, LANES),
+                         lambda i, c: (block(i, c), 0, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
+        scratch_shapes=[
+            pltpu.VMEM((chunk + 1, width, LANES), table.dtype),
+            pltpu.SMEM((chunk,), jnp.int32),
+            pltpu.SemaphoreType.DMA((2,)),
+        ],
+    )
+    out = pl.pallas_call(
+        functools.partial(_writeback_kernel, chunk=chunk, n_slots=n),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct(
+            sets.shape, sets.dtype, vma=out_vma(count, write_idx, rows, sets)
+        ),
+        input_output_aliases={3: 0},
+        name="slab_writeback",
+        interpret=interpret,
+    )(count, write_idx.astype(jnp.int32), rows, sets)
+    return out.transpose(0, 2, 1).reshape(n, width)

@@ -515,10 +515,11 @@ def _choose_ways(
 
 
 def _scatter_rows(table, write_idx, new_rows):
-    """The ONE row-scatter of the hot path. unique_indices: one writer per
-    slot by construction; dropped rows use the out-of-bounds index n
-    (mode='drop'). Without the flag XLA serializes the scatter. Standalone
-    so the slab_split stage baseline times the SHIPPED scatter."""
+    """The ONE row-scatter of the hot path (the Pallas arm at ways == 128
+    writes with pallas_slab_writeback instead; _finish_update). unique_indices:
+    one writer per slot by construction; dropped rows use the out-of-bounds
+    index n (mode='drop'). Without the flag XLA serializes the scatter.
+    Standalone so the slab_split stage baseline times the SHIPPED scatter."""
     return table.at[write_idx].set(new_rows, mode="drop", unique_indices=True)
 
 
@@ -573,9 +574,10 @@ def _slab_update_sorted(
     use_pallas=True swaps the arithmetic between the gathers — the W-way
     scan (pallas_way_scan), the segmented scans, window rollover,
     increment, and (with fuse_decide) the decision — for the Mosaic
-    kernels (ops/pallas_slab.py); the set gather, sort, picked-row select,
-    and row scatter stay XLA in both paths (they compile to the TPU's
-    native dynamic gather/scatter, which a kernel cannot beat). Returns an
+    kernels (ops/pallas_slab.py); the set gather, sort and picked-row
+    select stay XLA in both paths (they compile to the TPU's native
+    dynamic gather), and with ways == 128 the row write is the set-tile
+    kernel (_finish_update). Returns an
     extra trailing element: the fused DecideResult (sorted order) when
     fuse_decide, else None.
     Without fuse_decide there is no decision math — callers either decide on
@@ -739,7 +741,7 @@ def _slab_update_sorted(
                 expire_store, div_store, prev_store, aux_store,
                 algo_reset, count_health, decision,
                 sketch=sketch, sketch_ways=sketch_ways,
-                sketch_pallas=use_pallas, sketch_interpret=interpret,
+                use_pallas=use_pallas, interpret=interpret, ways=ways,
                 victim=victim, st_rows=st_rows,
             )
 
@@ -975,7 +977,7 @@ def _slab_update_sorted(
         div_store, prev_store, aux_store, algo_reset,
         count_health, decision,
         sketch=sketch, sketch_ways=sketch_ways,
-        sketch_pallas=use_pallas, sketch_interpret=interpret,
+        use_pallas=use_pallas, interpret=interpret, ways=ways,
         victim=victim, st_rows=st_rows,
     )
 
@@ -986,7 +988,7 @@ def _finish_update(
     s_before, s_after, count_store, window_store, expire_store,
     div_store, prev_store, aux_store, algo_reset,
     count_health, decision,
-    sketch=None, sketch_ways=0, sketch_pallas=False, sketch_interpret=False,
+    sketch=None, sketch_ways=0, use_pallas=False, interpret=False, ways=0,
     victim=False, st_rows=None,
 ):
     """The shared tail of _slab_update_sorted — one row write per slot,
@@ -1012,7 +1014,12 @@ def _finish_update(
     (backends/victim.py): the engine drains the nonzero lanes into the
     host table instead of letting the counters vanish. False compiles
     the byte-identical no-readback program — the VICTIM_TIER_ENABLED
-    rollback arm."""
+    rollback arm.
+
+    use_pallas with ways == 128 writes the rows back with the set-tile
+    kernel (ops/pallas_slab.py pallas_slab_writeback), whose work follows
+    the rows written rather than the launch width; every other shape
+    keeps the XLA row scatter."""
     # --- one row write per SLOT: the final item in the slot's run ---
     is_last = jnp.concatenate([s_slot[1:] != s_slot[:-1], jnp.array([True])])
     s_valid = s_hits > 0
@@ -1064,7 +1071,16 @@ def _finish_update(
         ],
         axis=1,
     )
-    table = _scatter_rows(state.table, write_idx, new_rows)
+    if use_pallas and ways == 128:
+        from .pallas_slab import pallas_slab_writeback
+
+        # padding lanes sort last (slot n): the kernel stops before them
+        table = pallas_slab_writeback(
+            state.table, write_idx, new_rows,
+            jnp.sum(s_valid, dtype=jnp.int32), interpret=interpret,
+        )
+    else:
+        table = _scatter_rows(state.table, write_idx, new_rows)
     base = (
         SlabState(table=table),
         s_before,
@@ -1102,7 +1118,7 @@ def _finish_update(
     cand = seg_last & (s_hits > 0)
     new_sketch = sketch_update(
         sketch, s_fp_lo, s_fp_hi, weight, cand, sketch_ways,
-        use_pallas=sketch_pallas, interpret=sketch_interpret,
+        use_pallas=use_pallas, interpret=interpret,
     )
     out = (*base, new_sketch)
     return out if not victim else (*out, victim_rows)
