@@ -152,7 +152,7 @@ chaos_smoke:
 	  --seed 1 --steps 30 --replay
 
 # Host-path profile: cProfile over the flat_per_second request loop
-# (tools/hotpath_profile.py; --legacy pins the pre-vectorization path).
+# (tools/hotpath_profile.py).
 profile:
 	$(PY) -m tools.hotpath_profile
 

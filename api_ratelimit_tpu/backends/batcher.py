@@ -1,42 +1,23 @@
-"""Micro-batcher: coalesce concurrent do_limit calls into one device launch.
+"""Direct-mode batcher: the caller executes its own row block.
 
-The TPU-native descendant of the reference's implicit Redis pipelining
-(src/redis/driver_impl.go:84-90: commands from concurrent goroutines are
-coalesced into one flush when REDIS_PIPELINE_WINDOW / REDIS_PIPELINE_LIMIT
-are set). Here the coalesced unit is a slab kernel launch instead of a Redis
-RTT: requests enqueue their items and block on a future; a single dispatcher
-thread drains the queue, waits up to `window` for stragglers (batch limit
-caps the wait), executes the batch callback once, and distributes results.
+With TPU_BATCH_WINDOW=0 (the default) every submit runs its own device
+launch on the calling thread, single-flight under the direct lock —
+lowest latency, no cross-request amortization (exactly like an unset
+pipeline window in the reference, src/redis/driver_impl.go:84-90).
+Windowed mode (TPU_BATCH_WINDOW > 0) coalesces on the device-owner
+dispatch loop instead (backends/dispatch.py).
 
-window=0 degenerates to direct mode: the caller executes its own items
-immediately under the dispatch lock — lowest latency, no cross-request
-amortization (exactly like an unset pipeline window in the reference).
-
-Double-buffered mode (execute_launch/execute_collect provided): the
-dispatcher splits each batch into a fast LAUNCH (pack + async device
-dispatch, returns a token) and a blocking COLLECT (device readback).
-Launch k+1 thus overlaps batch k's readback — the TPU analog of the
-reference keeping the next pipeline writing while the previous one's
-replies drain off the wire (src/redis/driver_impl.go:84-90).
-
-The collect runs in the CALLER threads (leader-collects): the dispatcher
-finishes its job at launch time by handing every future of the batch a
-collect ticket; the first waiter to wake redeems the whole batch's
-readback and the rest read their slices. Callers were going to block on
-exactly this readback anyway, so this removes a dedicated collector
-thread — and with it one cross-thread hand-off on every result path, a
-real scheduling cost on small hosts — while keeping the dispatcher free
-to launch the next batch. max_inflight still bounds un-collected launches
-(a semaphore held from launch to redemption) so latency stays bounded
-under backpressure.
+Both modes share the admission contract: the 'batcher.submit' chaos
+site and the brownout shed run before any lock work, and a propagated
+deadline that expires while the caller waits behind another launch
+resolves as DeadlineExceededError without reaching the device.
 """
 
 from __future__ import annotations
 
 import threading
 import time
-from concurrent.futures import Future
-from typing import Any, Callable
+from typing import Callable
 
 import numpy as np
 
@@ -45,59 +26,16 @@ from ..tracing import journeys
 from ..utils.deadline import current_deadline
 from .overload import BrownoutError, QueueFullError
 
-_TICKET = object()  # marks a future result as a deferred-collect ticket
-
 FAULT_SITE_SUBMIT = "batcher.submit"  # testing/faults.py chaos site
-
-
-class _CollectTicket:
-    """Deferred readback hand-off (leader-collects): the first caller to
-    redeem runs the batch's blocking collect; every other caller of the
-    same batch reads the memoized result (or re-raises the memoized
-    error). The ticket owns the inflight bookkeeping — _finish_one runs
-    exactly once, whoever redeems first."""
-
-    __slots__ = (
-        "_batcher", "_token", "_lock", "_results", "_error", "_done",
-        "stage_ns",
-    )
-
-    def __init__(self, batcher: "MicroBatcher", token, stage_partial=None):
-        self._batcher = batcher
-        self._token = token
-        self._lock = threading.Lock()
-        self._results = None
-        self._error: BaseException | None = None
-        self._done = False
-        # (take, pack, launch) monotonic-ns from the dispatcher thread;
-        # redeem/scatter appended by whoever redeems — the journey stage
-        # tuple (tracing/journeys.py), same shape as the dispatch loop's
-        self.stage_ns: tuple | None = stage_partial
-
-    def redeem(self):
-        with self._lock:
-            if not self._done:
-                try:
-                    self._results = self._batcher._execute_collect(self._token)
-                except BaseException as e:  # noqa: BLE001 - memo + reraise
-                    self._error = e
-                if self.stage_ns is not None and len(self.stage_ns) == 3:
-                    done_ns = time.monotonic_ns()
-                    self.stage_ns = (*self.stage_ns, done_ns, done_ns)
-                self._done = True
-                self._token = None
-                self._batcher._finish_one()
-        if self._error is not None:
-            raise self._error
-        return self._results
 
 
 class BatcherStats:
     """StatGenerator exporting the batcher's instantaneous backlog at every
     stats flush / metrics scrape:
 
-        <scope>.queue_depth   items enqueued awaiting a dispatcher take
-        <scope>.inflight      batches launched but not yet collected
+        <scope>.queue_depth   items awaiting execution (always 0: the
+                              caller executes its own block)
+        <scope>.inflight      launches executing right now
     """
 
     def __init__(self, batcher: "MicroBatcher", scope):
@@ -113,96 +51,38 @@ class BatcherStats:
 class MicroBatcher:
     def __init__(
         self,
-        execute: Callable[[list], list],
-        window_seconds: float = 0.0,
-        max_batch: int = 8192,
-        execute_launch: Callable[[list], Any] | None = None,
-        execute_collect: Callable[[Any], list] | None = None,
-        max_inflight: int = 2,
-        block_mode: bool = False,
+        execute: Callable[[list], np.ndarray],
         scope=None,
-        max_queue: int = 0,
         overload=None,
         fault_injector=None,
-        arena_rows: int = 0,
     ):
-        """block_mode: each submit() argument is ONE pre-packed uint32[6, n]
-        column block (the sidecar wire format) instead of a sequence of
-        per-item objects, and the executors receive a list of such blocks.
-        Same coalescing/window/double-buffer machinery — the unit taken per
-        future is the whole block, counts are in ITEMS (block columns), and
-        results may be one numpy array (sliced per future like a list).
-        This keeps the sidecar's aggregation path free of per-item Python
-        objects end to end.
+        """execute: runs a list of uint32[6, n] row blocks (the sidecar
+        wire layout) through one device launch and returns the uint32
+        results in row order. submit() hands it a one-block list.
 
         scope: optional stats Scope (stats/store.py). When set, the batcher
-        records its per-stage telemetry — queue_wait_ms (submit enqueue ->
-        batch take), batch_size (items per launch, pow-2 buckets) — and
-        registers a StatGenerator exporting queue_depth / inflight gauges
-        at every flush/scrape.
-
-        max_queue: hard bound on items awaiting a dispatcher take
-        (OVERLOAD_MAX_QUEUE); a submit that would exceed it raises
-        QueueFullError instantly instead of growing the queue without
-        bound. 0 keeps the legacy unbounded behavior.
+        records queue_wait_ms (the wait for the direct lock behind another
+        caller's launch) and batch_size (rows per launch, pow-2 buckets),
+        and registers a StatGenerator exporting queue_depth / inflight
+        gauges at every flush/scrape.
 
         overload: optional AdmissionController (backends/overload.py).
         When set, the batcher feeds it the queue-wait EWMA brownout signal
-        (one observation per take), sheds new submits with BrownoutError
+        (one observation per launch), sheds new submits with BrownoutError
         while the brownout is active, and reports deadline-expired drops.
 
         fault_injector: optional FaultInjector consulted at site
-        'batcher.submit' before each enqueue — delay_ms stalls the caller,
+        'batcher.submit' before each launch — delay_ms stalls the caller,
         queue_full raises QueueFullError — so chaos tests rehearse overload
-        deterministically (testing/faults.py).
-
-        arena_rows: block mode only — size (in items) of the preallocated
-        uint32[6, arena_rows] row ring submits write into. With a ring,
-        submit() COPIES the caller's block under the lock (one slot per
-        descriptor) and the queue holds views into the ring, so callers may
-        reuse a thread-local scratch block and the steady state allocates
-        nothing per request. Two ring buffers ping-pong: the dispatcher
-        packs taken views before its next take (same thread), so the ring
-        a batch was taken from is free again by the time the queue next
-        drains and the write side swaps to it. When the ring is full (or
-        the queue never fully drains under sustained overload) submits
-        fall back to an owned copy of the block — correctness is
-        unaffected, the per-request allocation just returns until the
-        queue drains. 0 keeps the legacy hand-off-ownership behavior
-        (sidecar wire blocks are one-shot buffers; copying them would be
-        pure waste)."""
+        deterministically (testing/faults.py)."""
         self._execute = execute
-        self._window = float(window_seconds)
-        self._max_batch = int(max_batch)
-        self._max_queue = int(max_queue)
         self._overload = overload
         self._faults = fault_injector
-        # deadline-expired items dropped before a launch (plain int — also
+        # deadline-expired submits dropped before a launch (plain int — also
         # mirrored into the overload controller's counter when one is wired)
         self.deadline_drops = 0
-        self._block_mode = bool(block_mode)
-        self._lock = threading.Lock()
-        self._items: list = []
-        self._pending = 0  # item count across self._items (== len in item mode)
-        # (future, start, count, enqueued_at)
-        self._futures: list[tuple[Future, int, int, float]] = []
-        self._inflight = 0
-        self._wakeup = threading.Condition(self._lock)
         self._direct_lock = threading.Lock()
         self._closed = False
-        self._last_end = float("-inf")  # monotonic end of the last execute
-        self._idle = threading.Condition(self._lock)
-        self._thread: threading.Thread | None = None
-        self._arenas = None
-        self._arena_idx = 0
-        self._arena_cursor = 0
-        self._arena_rows = 0
-        if self._block_mode and self._window > 0 and arena_rows > 0:
-            self._arena_rows = int(arena_rows)
-            self._arenas = [
-                np.empty((6, self._arena_rows), dtype=np.uint32),
-                np.empty((6, self._arena_rows), dtype=np.uint32),
-            ]
         self._h_wait = self._h_batch = None
         if scope is not None:
             from ..stats.store import DEFAULT_SIZE_BUCKETS
@@ -212,43 +92,21 @@ class MicroBatcher:
                 "batch_size", boundaries=DEFAULT_SIZE_BUCKETS
             )
             scope.add_stat_generator(BatcherStats(self, scope))
-        self._pipelined = execute_launch is not None and execute_collect is not None
-        self._execute_launch = execute_launch
-        self._execute_collect = execute_collect
-        # bounds launches whose collects haven't been redeemed yet — the
-        # backpressure the bounded collector queue used to provide
-        self._inflight_sem = threading.Semaphore(max(1, int(max_inflight)))
-        if self._window > 0:
-            self._thread = threading.Thread(
-                target=self._loop, name="tpu-batcher", daemon=True
-            )
-            self._thread.start()
 
     @property
     def queue_depth(self) -> int:
-        """Items awaiting a dispatcher take (racy read; stats only)."""
-        return self._pending
+        """Items awaiting execution: none, the caller executes its own."""
+        return 0
 
     @property
     def inflight(self) -> int:
-        """Batches launched but not yet finished (racy read; stats only)."""
-        return self._inflight
-
-    @property
-    def consumes_submits(self) -> bool:
-        """True when submit() fully consumes the caller's block before
-        returning (direct mode executes it; a row ring copies it) — i.e.
-        the caller may hand in a reusable scratch buffer. False means the
-        batcher retains the block and the caller must hand over
-        ownership."""
-        return self._window <= 0 or self._arenas is not None
-
-    # -- client side --
+        """Launches executing right now (racy read; stats only)."""
+        return int(self._direct_lock.locked())
 
     def _admit(self) -> None:
-        """Admission gate shared by both modes: chaos site, then the
-        brownout shed. Runs BEFORE any queue/lock work — overload is
-        answered at the cheapest possible point."""
+        """Admission gate: chaos site, then the brownout shed. Runs BEFORE
+        any lock work — overload is answered at the cheapest possible
+        point."""
         if self._faults is not None:
             action = self._faults.fire(FAULT_SITE_SUBMIT)
             if action == "queue_full":
@@ -258,116 +116,55 @@ class MicroBatcher:
                 "batcher brownout: queue wait ewma over target"
             )
 
-    def _expired(self, deadline: float | None) -> bool:
-        return deadline is not None and time.monotonic() >= deadline
+    def submit(self, block: np.ndarray) -> np.ndarray:
+        """Run one uint32[6, n] row block through the executor on this
+        thread; returns its uint32[n] results. The block is consumed before
+        submit returns, so the caller may reuse a scratch buffer.
 
-    def submit(self, items) -> list:
-        """Run `items` through the batch executor; returns their results in
-        order. Blocks until results are available. In block mode, `items`
-        is one uint32[6, n] block and the return is its uint32[n] result.
-
-        The caller's propagated deadline (utils/deadline.py) is captured at
-        enqueue: work already expired — here, or by the time the dispatcher
-        takes it — resolves as DeadlineExceededError without ever occupying
-        batch slots."""
-        count = items.shape[1] if self._block_mode else len(items)
+        The caller's propagated deadline (utils/deadline.py) is checked
+        once the direct lock is held: work that expired waiting behind
+        another caller's launch resolves as DeadlineExceededError without
+        reaching the device."""
+        count = block.shape[1]
         if count == 0:
-            return []
+            return np.empty(0, dtype=np.uint32)
         self._admit()
         deadline = current_deadline()
-        if self._window <= 0:
-            # direct mode: caller thread executes (single-flight via lock).
-            # queue_wait here is the time spent blocked on the dispatch
-            # lock behind another caller — the direct-mode analog of queue
-            # time, and the signal that a window would start paying off.
-            t_enq = time.monotonic()
-            with self._direct_lock:
-                if self._closed:
-                    # CacheError, not a bare RuntimeError: a submit racing
-                    # shutdown must surface as a counted backend failure
-                    # (redis_error + a proper wire error), not an unhandled
-                    # 500 from the transport
-                    raise CacheError("batcher is closed")
-                if self._expired(deadline):
-                    # time ran out waiting behind another caller's launch
-                    self._note_expired(1)
-                    raise DeadlineExceededError(
-                        "deadline expired before device dispatch"
-                    )
-                wait_ms = (time.monotonic() - t_enq) * 1e3
-                if self._h_wait is not None:
-                    self._h_wait.record(wait_ms)
-                    self._h_batch.record(count)
-                if self._overload is not None:
-                    self._overload.observe_queue_wait(wait_ms)
-                # journey stages in direct mode: the caller IS the owner,
-                # launch and readback are fused in one execute — stamp the
-                # full stage set (pinned by the dispatch-arm parity test)
-                # with the execute call as the launch..scatter interval
-                if journeys.recording():
-                    ns0 = time.monotonic_ns()
-                    for stage in ("publish", "take", "pack"):
-                        journeys.mark(stage, ns0)
-                    try:
-                        out = (
-                            self._execute([items])
-                            if self._block_mode
-                            else self._execute(list(items))
-                        )
-                    finally:
-                        ns1 = time.monotonic_ns()
-                        for stage in ("launch", "redeem", "scatter"):
-                            journeys.mark(stage, ns1)
-                    return out
-                if self._block_mode:
-                    return self._execute([items])
-                return self._execute(list(items))
-
-        journeys.mark("publish")
-        future: Future = Future()
-        with self._lock:
+        # queue_wait is the time spent blocked on the direct lock behind
+        # another caller — the signal that a window would start paying off
+        t_enq = time.monotonic()
+        with self._direct_lock:
             if self._closed:
-                raise CacheError("batcher is closed")  # see direct-mode note
-            if self._max_queue > 0 and self._pending + count > self._max_queue:
-                raise QueueFullError(
-                    f"batcher queue full ({self._pending} pending, "
-                    f"max {self._max_queue})"
+                # CacheError, not a bare RuntimeError: a submit racing
+                # shutdown must surface as a counted backend failure
+                # (redis_error + a proper wire error), not an unhandled
+                # 500 from the transport
+                raise CacheError("batcher is closed")
+            if deadline is not None and time.monotonic() >= deadline:
+                self._note_expired(1)
+                raise DeadlineExceededError(
+                    "deadline expired before device dispatch"
                 )
-            start = self._pending
-            if self._block_mode:
-                arenas = self._arenas
-                if arenas is not None:
-                    cursor = self._arena_cursor
-                    if cursor + count <= self._arena_rows:
-                        # row ring: one slot per descriptor, written in
-                        # place; the queue holds a view, the caller keeps
-                        # its scratch
-                        arena = arenas[self._arena_idx]
-                        arena[:, cursor : cursor + count] = items
-                        items = arena[:, cursor : cursor + count]
-                        self._arena_cursor = cursor + count
-                    else:
-                        # ring full: decouple from the caller's scratch
-                        # with an owned copy (rare; see arena_rows note)
-                        items = np.array(items, dtype=np.uint32)
-                self._items.append(items)
-            else:
-                self._items.extend(items)
-            self._pending += count
-            self._futures.append(
-                (future, start, count, time.monotonic(), deadline)
-            )
-            self._wakeup.notify()
-        out = future.result()
-        if type(out) is tuple and len(out) == 4 and out[0] is _TICKET:
-            # leader-collects: this caller (or a batch-mate that woke
-            # first) runs the blocking readback right here
-            _, ticket, start, count = out
-            results = ticket.redeem()
-            if ticket.stage_ns is not None:
-                journeys.merge_owner_stages(ticket.stage_ns)
-            return results[start : start + count]
-        return out
+            wait_ms = (time.monotonic() - t_enq) * 1e3
+            if self._h_wait is not None:
+                self._h_wait.record(wait_ms)
+                self._h_batch.record(count)
+            if self._overload is not None:
+                self._overload.observe_queue_wait(wait_ms)
+            if not journeys.recording():
+                return self._execute([block])
+            # the caller IS the owner: launch and readback are fused in
+            # one execute, so stamp the dispatch loop's full stage set with
+            # the execute call as the launch..scatter interval
+            ns0 = time.monotonic_ns()
+            for stage in ("publish", "take", "pack"):
+                journeys.mark(stage, ns0)
+            try:
+                return self._execute([block])
+            finally:
+                ns1 = time.monotonic_ns()
+                for stage in ("launch", "redeem", "scatter"):
+                    journeys.mark(stage, ns1)
 
     def _note_expired(self, n: int) -> None:
         self.deadline_drops += n
@@ -375,221 +172,17 @@ class MicroBatcher:
             self._overload.note_deadline_expired(n)
 
     def flush(self) -> None:
-        """Block until everything enqueued so far has executed (including a
-        batch already taken by the dispatcher and mid-execution)."""
-        if self._window <= 0:
-            with self._direct_lock:
-                return
-        with self._lock:
-            while self._items or self._futures or self._inflight:
-                self._idle.wait(timeout=0.05)
+        """Block until the launch executing right now (if any) is done."""
+        with self._direct_lock:
+            return
 
     def drain(self) -> None:
-        """Graceful-drain quiesce: refuse new submits from now on, then
-        block until everything already enqueued (including a batch the
-        dispatcher took and any launch in flight) has executed. The
-        warm-restart handoff runs this before the final slab snapshot
-        (persist/snapshotter.py) so a planned restart captures every
-        decision that was admitted; unlike close(), worker threads are
-        left to wind down on their own and close() still follows."""
-        if self._window <= 0:
-            with self._direct_lock:
-                self._closed = True
-            return
-        with self._lock:
-            self._closed = True
-            self._wakeup.notify_all()
-            while self._items or self._futures or self._inflight:
-                self._idle.wait(timeout=0.05)
+        """Graceful-drain quiesce: wait out the launch in progress, then
+        refuse new submits. The warm-restart handoff runs this before the
+        final slab snapshot (persist/snapshotter.py) so a planned restart
+        captures every decision that was admitted."""
+        self.close()
 
     def close(self) -> None:
-        if self._window <= 0:
-            with self._direct_lock:
-                self._closed = True
-            return
-        with self._lock:
+        with self._direct_lock:
             self._closed = True
-            self._wakeup.notify_all()
-        if self._thread is not None:
-            self._thread.join(timeout=1.0)
-
-    # -- dispatcher --
-
-    def _loop(self) -> None:
-        while True:
-            with self._lock:
-                while not self._items and not self._closed:
-                    self._wakeup.wait()
-                if self._closed and not self._items:
-                    self._idle.notify_all()
-                    break
-                # linger up to `window` for stragglers unless already full.
-                # Warm pipeline: items enqueued while the previous batch was
-                # executing have already waited >= one launch — launch them
-                # immediately instead of adding the window on top (the device
-                # execute time is itself the coalescing window under load).
-                # A batch still in flight is the same signal: its execute
-                # time IS the coalescing delay for everything queued behind
-                # it, so lingering on top would stack latency for nothing.
-                # submit() notifies on every enqueue, so wait on a deadline
-                # loop or the first straggler would end the window early
-                warm = self._inflight > 0 or (
-                    self._futures and self._futures[0][3] <= self._last_end
-                )
-                if self._pending < self._max_batch and not warm:
-                    # Lull cutoff: concurrent submitters arrive within each
-                    # other's host think time, far inside the window. Once
-                    # a quarter-window passes with NO new enqueue, the
-                    # straggler train has ended — launch now instead of
-                    # idling out the rest of the window (measured: the
-                    # full-window linger was the service tier's dominant
-                    # per-cycle cost at closed-loop concurrency; lingering
-                    # while warm measured strictly worse — the in-flight
-                    # launch already provides the coalescing delay).
-                    now = time.monotonic()
-                    deadline = now + self._window
-                    lull = self._window * 0.25
-                    last_pending = self._pending
-                    last_change = now
-                    while self._pending < self._max_batch and not self._closed:
-                        now = time.monotonic()
-                        if now >= deadline:
-                            break
-                        if self._pending != last_pending:
-                            last_pending = self._pending
-                            last_change = now
-                        elif now - last_change >= lull:
-                            break
-                        self._wakeup.wait(
-                            timeout=min(
-                                deadline - now,
-                                lull - (now - last_change),
-                            )
-                        )
-                # Take whole requests only — a request's items never split
-                # across launches (its future completes from one result set).
-                # A single oversized request is taken alone; the executor
-                # loops over buckets internally. Block mode: one submitted
-                # block per future, so taking k futures takes k blocks.
-                # Requests whose propagated deadline expired while queued
-                # are DROPPED here, before packing: they resolve as
-                # DeadlineExceededError and never consume batch slots.
-                futures = []
-                expired: list[Future] = []
-                taken = 0  # live items in this batch
-                dropped = 0  # expired items excised from the queue
-                kept: list[tuple[int, int]] = []  # (unit offset, unit len)
-                unit_cursor = 0
-                consumed = 0
-                head_wait_ms = 0.0
-                t_take = time.monotonic()
-                for future, _start, count, ts, dl in self._futures:
-                    units = 1 if self._block_mode else count
-                    if dl is not None and t_take >= dl:
-                        expired.append(future)
-                        dropped += count
-                        unit_cursor += units
-                        consumed += 1
-                        continue
-                    if futures and taken + count > self._max_batch:
-                        break
-                    if self._h_wait is not None:
-                        self._h_wait.record((t_take - ts) * 1e3)
-                    if not futures:
-                        # oldest live request's wait — the brownout signal
-                        head_wait_ms = (t_take - ts) * 1e3
-                    futures.append((future, taken, count))
-                    taken += count
-                    kept.append((unit_cursor, units))
-                    unit_cursor += units
-                    consumed += 1
-                if self._h_batch is not None and futures:
-                    self._h_batch.record(taken)
-                if dropped:
-                    items = []
-                    for off, units in kept:
-                        items.extend(self._items[off : off + units])
-                else:
-                    items = self._items[:unit_cursor]
-                self._items = self._items[unit_cursor:]
-                if self._arenas is not None and not self._items:
-                    # queue drained: new submits write the OTHER ring. The
-                    # ring just taken is packed by this thread's launch
-                    # BEFORE the next take, so by the time the write side
-                    # swaps back to it, nothing references its rows.
-                    self._arena_idx ^= 1
-                    self._arena_cursor = 0
-                self._pending -= taken + dropped
-                removed = taken + dropped
-                self._futures = [
-                    (f, start - removed, count, ts, dl)
-                    for f, start, count, ts, dl in self._futures[consumed:]
-                ]
-                if futures:
-                    self._inflight += 1
-
-            if expired:
-                self._note_expired(len(expired))
-                exc = DeadlineExceededError(
-                    "deadline expired in batcher queue"
-                )
-                for future in expired:
-                    if not future.done():
-                        future.set_exception(exc)
-            if not futures:
-                # pure-expiry round: nothing to launch
-                with self._lock:
-                    if not self._items and not self._futures and not self._inflight:
-                        self._idle.notify_all()
-                continue
-            if self._overload is not None:
-                self._overload.observe_queue_wait(head_wait_ms)
-
-            if self._pipelined:
-                # double-buffered: launch now (fast), defer the blocking
-                # readback to the callers via a collect ticket. The
-                # semaphore (held launch -> redemption) is the
-                # backpressure that caps un-collected launches.
-                self._inflight_sem.acquire()
-                stage_partial = None
-                if journeys.recording():
-                    # take/pack/launch for the journey stage tuple; the
-                    # redeeming caller appends redeem/scatter — the same
-                    # stage set the dispatch loop records, pinned by test
-                    take_ns = int(t_take * 1e9)
-                    stage_partial = (take_ns, time.monotonic_ns())
-                try:
-                    token = self._execute_launch(items)
-                except BaseException as e:  # noqa: BLE001 - propagate
-                    for future, _, _ in futures:
-                        if not future.done():
-                            future.set_exception(e)
-                    self._finish_one()
-                else:
-                    if stage_partial is not None:
-                        stage_partial = (
-                            *stage_partial, time.monotonic_ns()
-                        )
-                    ticket = _CollectTicket(self, token, stage_partial)
-                    for future, start, count in futures:
-                        future.set_result((_TICKET, ticket, start, count))
-                continue
-
-            try:
-                results = self._execute(items)
-                for future, start, count in futures:
-                    future.set_result(results[start : start + count])
-            except BaseException as e:  # noqa: BLE001 - propagate to callers
-                for future, _, _ in futures:
-                    if not future.done():
-                        future.set_exception(e)
-            self._finish_one()
-
-    def _finish_one(self) -> None:
-        with self._lock:
-            self._last_end = time.monotonic()
-            self._inflight -= 1
-            if not self._items and not self._futures and not self._inflight:
-                self._idle.notify_all()
-        if self._pipelined:
-            self._inflight_sem.release()

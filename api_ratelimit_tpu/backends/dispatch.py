@@ -1,13 +1,12 @@
-"""Persistent device-owner dispatch loop (DISPATCH_LOOP, default on).
+"""Persistent device-owner dispatch loop: windowed mode (TPU_BATCH_WINDOW
+> 0).
 
-PERF.md round 6 left the service tier at the JAX per-launch dispatch floor:
-~0.14-0.18 ms of launch bookkeeping executed under GIL contention, because
-the leader-collects batcher makes CALLER threads redeem readbacks — every
-frontend thread takes turns touching JAX while the others fight it for the
-interpreter. This module tears that floor down structurally, the same
-"pipeline the RTT instead of paying it per call" move the reference makes
-for Redis (src/redis/driver_impl.go:84-90 keeps the next pipeline writing
-while the previous one's replies drain off the wire):
+Frontend threads never touch JAX: one owner thread launches and redeems
+every batch, so callers do not take turns at launch state while the
+others fight them for the interpreter. It is the same "pipeline the RTT
+instead of paying it per call" move the reference makes for Redis
+(src/redis/driver_impl.go:84-90 keeps the next pipeline writing while the
+previous one's replies drain off the wire):
 
   * ONE device-owner thread runs a continuous launch -> redeem cycle with
     two batches in flight, double-buffered: while batch k's readback
@@ -27,12 +26,12 @@ while the previous one's replies drain off the wire):
     scatters the batch's verdicts back (native codec rl_scatter_rows when
     built, numpy slice copies otherwise) and sets the ticket event.
 
-Admission parity with the leader-collects arm (backends/batcher.py, the
-DISPATCH_LOOP=false rollback): the same 'batcher.submit' chaos site and
-brownout shed run before any ring work, OVERLOAD_MAX_QUEUE bounds the
-summed ring backlog with QueueFullError, deadline-expired frames are
-dropped at ring TAKE time — before packing, never consuming launch slots —
-and queue-wait feeds the same AdmissionController EWMA. The owner thread
+Admission parity with direct mode (backends/batcher.py): the same
+'batcher.submit' chaos site and brownout shed run before any ring work,
+OVERLOAD_MAX_QUEUE bounds the summed ring backlog with QueueFullError,
+deadline-expired frames are dropped at ring TAKE time — before packing,
+never consuming launch slots — and queue-wait feeds the same
+AdmissionController EWMA. The owner thread
 additionally consults the 'dispatch.launch' fault site before each device
 launch (delay_ms = a stalled device owner, error = a failed launch) so the
 chaos suite can exercise the breaker/brownout machinery against a wedged
@@ -73,7 +72,8 @@ _MASK64 = 0xFFFFFFFFFFFFFFFF
 _CTX_PRESENT = 1
 _CTX_SAMPLED = 2
 
-# shared with MicroBatcher so one FAULT_INJECT spec rehearses both arms
+# shared with direct mode's MicroBatcher so one FAULT_INJECT spec
+# rehearses both modes
 FAULT_SITE_SUBMIT = "batcher.submit"
 # owner-thread site: fires before each device launch (testing/faults.py)
 FAULT_SITE_LAUNCH = "dispatch.launch"

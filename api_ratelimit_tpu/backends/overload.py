@@ -9,7 +9,7 @@ the whole admission path).
 
 Two shed triggers, one policy:
 
-    QueueFullError      the micro-batcher's hard OVERLOAD_MAX_QUEUE bound
+    QueueFullError      the dispatch loop's hard OVERLOAD_MAX_QUEUE bound
     BrownoutError       the latency brownout — EWMA of batcher queue wait
                         crossed OVERLOAD_BROWNOUT_TARGET_MS (hysteresis:
                         exits below OVERLOAD_BROWNOUT_EXIT_MS)
@@ -60,7 +60,8 @@ class OverloadError(CacheError):
 
 
 class QueueFullError(OverloadError):
-    """The micro-batcher queue is at its hard OVERLOAD_MAX_QUEUE bound."""
+    """The dispatch loop's ring backlog is at its hard OVERLOAD_MAX_QUEUE
+    bound."""
 
     token = "queue_full"
 
@@ -144,7 +145,7 @@ class AdmissionController:
         else:
             self._c_deadline = None
 
-    # -- brownout signal (fed by the micro-batcher) --
+    # -- brownout signal (fed by the batching layer) --
 
     @property
     def brownout(self) -> bool:
@@ -155,8 +156,9 @@ class AdmissionController:
         return self._ewma_ms
 
     def observe_queue_wait(self, ms: float) -> None:
-        """EWMA update + hysteresis. Called once per batch take (windowed
-        mode) or per submit (direct mode) by the micro-batcher."""
+        """EWMA update + hysteresis. Called once per batch take by the
+        dispatch loop (windowed mode) or per submit by the direct-mode
+        batcher."""
         if self._target_ms <= 0:
             return
         with self._lock:

@@ -59,7 +59,7 @@ without a syscall per request in steady state.
 
 SHM_RINGS=false (settings) keeps every byte of this module out of the
 path — the byte-identical rollback arm, same discipline as
-HOST_FAST_PATH / DISPATCH_LOOP / LEASE_ENABLED.
+LEASE_ENABLED.
 
 This module deliberately imports no JAX: frontend worker processes load
 it without touching the device stack.

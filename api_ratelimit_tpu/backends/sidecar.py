@@ -8,7 +8,7 @@ common.hcl:2) — the Redis process is the shared single-writer state. Here
 the TPU chip plays Redis's role: ONE sidecar process owns the slab
 (SlabDeviceEngine, backends/tpu.py) and N frontend processes — each a full
 gRPC/HTTP server bound to the same ports via SO_REUSEPORT — ship item
-batches to it over a unix socket. The sidecar's micro-batcher coalesces
+batches to it over a unix socket. The sidecar's dispatch loop coalesces
 across ALL frontends, so more frontends means BIGGER device batches, not
 contention. Limits stay globally exact because every increment serializes
 through the one slab, exactly like N replicas against one Redis.
@@ -309,7 +309,7 @@ def decode_items(payload: bytes):
 class SlabSidecarServer:
     """The device-owner process. Accepts frontend connections on a unix
     socket or TCP(+TLS) listener; each SUBMIT runs through the engine's
-    micro-batcher, which coalesces items from every connected frontend into
+    dispatch loop, which coalesces items from every connected frontend into
     shared launches."""
 
     def __init__(
@@ -389,7 +389,7 @@ class SlabSidecarServer:
             if loop is None:
                 logger.warning(
                     "SHM_RINGS requested but the engine has no dispatch "
-                    "loop (direct mode / DISPATCH_LOOP=false): shm "
+                    "loop (direct mode, TPU_BATCH_WINDOW=0): shm "
                     "control socket NOT started, socket RPC only"
                 )
             else:

@@ -2,8 +2,8 @@
 
 Replaces the reference's Redis hot path (src/redis/fixed_cache_impl.go) with
 an in-process TPU device program: descriptors are fingerprinted on the host
-(ops/hashing.py, xxhash), concurrent requests coalesce in the micro-batcher
-(backends/batcher.py — the TPU analog of implicit Redis pipelining), and one
+(ops/hashing.py, xxhash), concurrent requests coalesce in the dispatch loop
+(backends/dispatch.py — the TPU analog of implicit Redis pipelining), and one
 jitted launch executes probe + window-reset + duplicate-serialized increment
 against the HBM slab (ops/slab.py).
 
@@ -121,7 +121,7 @@ class _Item:
 
 class SlabDeviceEngine:
     """The device driver: owns the slab state (single-chip or mesh-sharded)
-    and the micro-batcher, and turns item batches into post-increment
+    and the batching layer, and turns item batches into post-increment
     counters via one launch per batch. The narrow `submit(items) -> afters`
     verb set is the device analog of the reference's redis.Client interface
     (SURVEY.md §2.9); TpuRateLimitCache drives it in-process and the sidecar
@@ -147,7 +147,6 @@ class SlabDeviceEngine:
         overload=None,
         fault_injector=None,
         precompile: bool = False,
-        dispatch_loop: bool = True,
         gcra_burst_ratio: float = 1.0,
         partition: int = -1,
         hotkey_lanes: int = 0,
@@ -187,8 +186,8 @@ class SlabDeviceEngine:
         the runner's `ratelimit` scope). When set, the engine records the
         per-stage device histograms — <scope>.device.{pack_ms,launch_ms,
         readback_ms}, <scope>.slab.{lock_wait_ms,health_drain_ms,
-        health_vectors} — and
-        hands <scope>.batcher to the micro-batcher for
+        health_vectors} — and hands <scope>.batcher to the direct-mode
+        batcher and <scope>.dispatch to the dispatch loop for their
         queue-wait/batch-size/depth telemetry. None (the default) keeps
         the hot path entirely free of stats work.
 
@@ -196,17 +195,16 @@ class SlabDeviceEngine:
         readback dtype) at construction so no request ever rides a JIT
         compile (see precompile()).
 
-        max_queue / overload / fault_injector are forwarded to the
-        micro-batcher (bounded queue + brownout shedding + the
-        batcher.submit chaos site; backends/batcher.py).
+        batch_window_seconds: 0 (direct mode) makes each caller execute
+        its own launch under the direct lock (backends/batcher.py); > 0
+        (windowed mode) runs the persistent device-owner dispatch loop
+        (backends/dispatch.py): one thread owns every launch AND readback,
+        fed by per-frontend-thread submit rings, with two batches
+        double-buffered in flight.
 
-        dispatch_loop: windowed mode only — run the persistent device-owner
-        dispatch loop (backends/dispatch.py): one thread owns every launch
-        AND readback, fed by per-frontend-thread submit rings, with two
-        batches double-buffered in flight. False (DISPATCH_LOOP=false)
-        falls back to the leader-collects micro-batcher — the rollback
-        arm, same contract HOST_FAST_PATH set. Direct mode (window 0)
-        ignores this knob.
+        overload / fault_injector feed both modes' admission (brownout
+        shedding + the batcher.submit chaos site); max_queue bounds the
+        dispatch loop's ring backlog (direct mode holds no queue).
 
         ways: set associativity (SLAB_WAYS) — the slab is n_slots/ways
         sets of `ways` rows; a full set evicts its least-valuable way
@@ -368,14 +366,6 @@ class SlabDeviceEngine:
         # evicting least-valuable ways in-kernel.
         self._watermark_high = float(watermark_high)
         self._watermark_state = 0  # 0 normal / 1 high
-        # Both modes run double-buffered: the dispatcher's launch (pack +
-        # owner routing in mesh mode + async device dispatch) of batch k+1
-        # overlaps the collector's blocking readback of batch k (ADVICE r3:
-        # the p99 fix is pipelining in the dispatch path, not lock
-        # narrowing; VERDICT r4 weak #2 extended the split to the sharded
-        # engine's compacted path). block_mode (the sidecar server) swaps
-        # the item-list executors for the wire-block ones; the batcher
-        # machinery is shared.
         self._h_pack = self._h_launch = self._h_readback = None
         # the launch path's wait for _state_lock, and each health drain
         # (stats flush, or inline once 4,096 vectors are parked): its time
@@ -398,24 +388,19 @@ class SlabDeviceEngine:
             )
             batcher_scope = scope.scope("batcher")
         install_gc_spans()
-        # Every engine is block-native internally: the batcher's unit is a
+        # Every engine is block-native internally: the submit unit is a
         # uint32[6, n] row block and the executors copy whole column spans
         # into the padded device block — the in-process frontend rides the
         # same zero-object machinery the sidecar server proved (8x at
-        # aggregated load). block_mode only selects the PUBLIC verb set
-        # (submit_block for the sidecar wire path vs submit/submit_rows for
-        # in-process callers) and whether the batcher gets a row ring:
-        # sidecar wire blocks are one-shot buffers handed over by the
-        # server loop, while in-process submits come from reusable
-        # thread-local scratch, which the ring copies out of under the
-        # enqueue lock (one slot per descriptor).
+        # aggregated load). block_mode only selects the PUBLIC verb set:
+        # submit_block for the sidecar wire path vs submit/submit_rows for
+        # in-process callers.
         self._block_batcher = bool(block_mode)
         # Padded-operand reuse (single device only): per-bucket ping-pong
         # pairs the launch path packs into instead of allocating fresh
-        # zeros every launch. Safe because every launch arm bounds
-        # un-redeemed launches to 2 (the dispatch loop's double buffer,
-        # the batcher's max_inflight semaphore, direct mode's full
-        # serialization), so a buffer is only rewritten after the launch
+        # zeros every launch. Safe because both modes bound un-redeemed
+        # launches to 2 (the dispatch loop's double buffer, direct mode's
+        # full serialization), so a buffer is only rewritten after the launch
         # 2-back has finished executing — its input can no longer be read
         # even if XLA aliased the host memory. Padding correctness: only
         # the hits row gates device writes (ops/slab.py), so the fill path
@@ -432,25 +417,17 @@ class SlabDeviceEngine:
             self._pack_rows = _native.pack_rows if _native.available() else None
         except Exception:  # noqa: BLE001 - codec is strictly optional
             self._pack_rows = None
+        # direct mode's batcher is built in both modes (it holds no
+        # thread), so the batcher.* metric names are exported either way;
+        # in windowed mode the dispatch loop takes every submit
         self._dispatch = None
-        use_loop = bool(dispatch_loop) and batch_window_seconds > 0
         self._batcher = MicroBatcher(
             self._execute_blocks,
-            # with the dispatch loop active the batcher serves only as the
-            # direct-mode fallback for legacy single-shot launches
-            # (_launch, tools); its dispatcher thread would sit idle
-            window_seconds=0.0 if use_loop else batch_window_seconds,
-            max_batch=max_batch,
-            execute_launch=self._execute_blocks_launch,
-            execute_collect=self._execute_blocks_collect,
-            block_mode=True,
             scope=batcher_scope,
-            max_queue=max_queue,
             overload=overload,
             fault_injector=fault_injector,
-            arena_rows=0 if block_mode else min(2 * int(max_batch), 1 << 17),
         )
-        if use_loop:
+        if batch_window_seconds > 0:
             from .dispatch import DispatchLoop
 
             self._dispatch = DispatchLoop(
@@ -708,8 +685,7 @@ class SlabDeviceEngine:
         """Zero-object verb: one uint32[6, n] row block (columns fp_lo,
         fp_hi, hits, limit, divider, jitter — the sidecar wire layout) ->
         uint32[n] post-increment counters. The caller may pass a reusable
-        scratch block: when the batcher doesn't consume submits (no row
-        ring configured), an owned copy decouples it here.
+        scratch block: both modes consume it before returning.
 
         lease_ops: optional backends.lease.LeaseOps piggybacked on this
         submit — grants registered against the liability registry with the
@@ -725,10 +701,7 @@ class SlabDeviceEngine:
             # consumes them immediately)
             afters = self._dispatch.submit(block, reuse_out=True)
         else:
-            wire = block
-            if not self._batcher.consumes_submits:
-                wire = np.array(block, dtype=np.uint32)
-            afters = self._batcher.submit(wire)
+            afters = self._batcher.submit(block)
         if lease_ops is not None:
             self.apply_lease_ops(block, afters, lease_ops)
         return afters
@@ -748,8 +721,8 @@ class SlabDeviceEngine:
 
     @property
     def dispatch_loop(self):
-        """The device-owner dispatch loop, or None (direct mode /
-        DISPATCH_LOOP=false). The shm-ring control server
+        """The device-owner dispatch loop, or None in direct mode
+        (TPU_BATCH_WINDOW=0). The shm-ring control server
         (backends/shm_ring.py) attaches cross-process frontend rings
         here."""
         return self._dispatch
@@ -761,10 +734,10 @@ class SlabDeviceEngine:
 
     def drain(self) -> None:
         """Graceful-drain quiesce: refuse new submits, finish everything
-        already queued (dispatch rings and/or batcher). The warm-restart
-        snapshotter calls this before its final snapshot so a planned
-        restart hands over every admitted decision
-        (persist/snapshotter.py)."""
+        already queued (dispatch rings, or direct mode's launch in
+        progress). The warm-restart snapshotter calls this before its
+        final snapshot so a planned restart hands over every admitted
+        decision (persist/snapshotter.py)."""
         if self._dispatch is not None:
             self._dispatch.drain()
         self._batcher.drain()
@@ -928,7 +901,7 @@ class SlabDeviceEngine:
         self.import_tables(tables)
         self.lease_registry.import_rows(lease_rows)
 
-    # -- device execution (dispatcher thread / direct-mode caller only) --
+    # -- device execution (dispatch owner thread / direct-mode caller only) --
 
     def _bucket_for(self, n: int) -> int:
         for b in self._buckets:
@@ -1657,7 +1630,6 @@ class TpuRateLimitCache:
         overload=None,
         fault_injector=None,
         precompile: bool = False,
-        dispatch_loop: bool = True,
         lease_table=None,
         gcra_burst_ratio: float = 1.0,
         hotkey_lanes: int = 0,
@@ -1719,7 +1691,6 @@ class TpuRateLimitCache:
                 overload=overload,
                 fault_injector=fault_injector,
                 precompile=precompile,
-                dispatch_loop=dispatch_loop,
                 gcra_burst_ratio=gcra_burst_ratio,
                 hotkey_lanes=hotkey_lanes,
                 hotkey_k=hotkey_k,
@@ -1764,8 +1735,10 @@ class TpuRateLimitCache:
         # engines quietly run unleased
         self._lease = lease_table if self._submit_rows is not None else None
         # per-thread scratch row block: do_limit_resolved fills columns in
-        # place and the batcher's row ring copies them out under its lock,
-        # so the steady-state request path allocates no numpy buffers
+        # place and the engine consumes it before the submit returns (the
+        # dispatch loop copies it into this thread's submit ring; direct
+        # mode executes it), so the steady-state request path allocates no
+        # numpy buffers
         self._scratch = threading.local()
         # host-stage histograms (bench host_split + GET /metrics): the
         # descriptor-admission/key-compose loop and the status-build loop,
@@ -1838,7 +1811,7 @@ class TpuRateLimitCache:
 
     @property
     def _batcher(self):
-        """Test seam: the in-process engine's micro-batcher."""
+        """Test seam: the in-process engine's direct-mode batcher."""
         return self._engine_core._batcher
 
     # -- RateLimitCache interface --
@@ -1976,7 +1949,7 @@ class TpuRateLimitCache:
         adds, the optional local-cache probe (key = precomputed prefix +
         window — no joins), and six uint32 column writes into this
         thread's scratch block; the whole request then submits as ONE row
-        block into the batcher's ring. Decision-identical to do_limit by
+        block to the engine. Decision-identical to do_limit by
         construction: the same BaseRateLimiter oracle builds every status
         (differential-tested in tests/test_compiled_matcher.py)."""
         base = self._base

@@ -62,8 +62,8 @@ raises the fence floor to "now", so pre-crash grants can only be
 reclaimed, never settled — re-tightening instead of diverging.
 
 FED_ENABLED=false builds none of this: no coordinator, no wire op, the
-byte-identical rollback arm (pinned by test, the HOST_FAST_PATH /
-DISPATCH_LOOP / LEASE discipline).
+byte-identical rollback arm (pinned by test, the LEASE_ENABLED
+discipline).
 """
 
 from __future__ import annotations

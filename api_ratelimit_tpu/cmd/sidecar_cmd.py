@@ -248,11 +248,6 @@ def main(argv=None) -> None:
         # the device owner must never spend a frontend's RPC deadline on
         # a first-touch XLA compile
         precompile=settings.tpu_precompile,
-        # the device-owner dispatch loop (backends/dispatch.py): the
-        # sidecar IS the deployment shape it was built for — frontends'
-        # wire frames coalesce in the rings while one thread owns every
-        # launch; DISPATCH_LOOP=false falls back to leader-collects
-        dispatch_loop=settings.dispatch_loop,
         # partition labeling for the arena-pressure telemetry
         # (DispatchStats): ring pressure on a K-partition host traces to
         # the keyspace slice generating it

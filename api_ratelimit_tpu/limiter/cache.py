@@ -31,7 +31,7 @@ class CacheError(Exception):
 
 class DeadlineExceededError(CacheError):
     """The request's propagated deadline (utils/deadline.py) expired before
-    the backend could answer — raised by the micro-batcher when it drops
+    the backend could answer — raised by the batching layer when it drops
     expired items ahead of a device launch, or by the service when a
     request arrives already expired. The transport maps it to gRPC
     DEADLINE_EXCEEDED / HTTP 504: a late answer is worthless to a caller

@@ -97,7 +97,7 @@ def create_limiter(
     (batcher.queue_wait_ms, device.{pack,launch,readback}_ms,
     sidecar.rpc_ms) land in the same store /metrics scrapes.
     fault_injector (FAULT_INJECT) reaches the sidecar client's and the
-    micro-batcher's chaos sites; overload (the AdmissionController) wires
+    batching layer's chaos sites; overload (the AdmissionController) wires
     the bounded-queue/brownout/watermark admission layer into the
     in-process TPU engine."""
     backend = settings.backend_type
@@ -132,7 +132,6 @@ def create_limiter(
             # the bucket ladder compiles BEFORE the server reports
             # healthy: no request ever rides a first-touch XLA compile
             precompile=settings.tpu_precompile,
-            dispatch_loop=settings.dispatch_loop,
             lease_table=lease_table,
             gcra_burst_ratio=settings.gcra_burst(),
             hotkey_lanes=hk_lanes if hk_enabled else 0,
@@ -372,9 +371,8 @@ class Runner:
 
         # Hierarchical quota leasing (LEASE_ENABLED; backends/lease.py):
         # the frontend lease table answers hot-key decisions locally from
-        # device-granted budget slices. Rides the compiled-matcher fast
-        # path — HOST_FAST_PATH=false (the vectorization rollback arm)
-        # disables leasing with it.
+        # device-granted budget slices. Rides the compiled-matcher
+        # pipeline (do_limit_resolved).
         self.lease_table = None
         (
             lease_on,
@@ -384,24 +382,19 @@ class Runner:
             lease_near,
         ) = settings.lease_config()
         if lease_on and settings.backend_type in ("tpu", "tpu-sidecar"):
-            if not settings.host_fast_path:
-                logger.warning(
-                    "LEASE_ENABLED requires HOST_FAST_PATH; leasing disabled"
-                )
-            else:
-                from .backends.lease import LeaseTable
+            from .backends.lease import LeaseTable
 
-                self.lease_table = LeaseTable(
-                    base,
-                    min_size=lease_min,
-                    max_size=lease_max,
-                    ttl_fraction=lease_ttl,
-                    near_limit_ratio=lease_near,
-                    scope=self.scope.scope("lease"),
-                )
-                self.server.health.add_degraded_probe(
-                    self.lease_table.degraded_reason
-                )
+            self.lease_table = LeaseTable(
+                base,
+                min_size=lease_min,
+                max_size=lease_max,
+                ttl_fraction=lease_ttl,
+                near_limit_ratio=lease_near,
+                scope=self.scope.scope("lease"),
+            )
+            self.server.health.add_degraded_probe(
+                self.lease_table.degraded_reason
+            )
 
         # Global quota federation (FED_ENABLED; cluster/federation.py):
         # an in-process device owner (BACKEND_TYPE=tpu) hosts its own
@@ -635,7 +628,6 @@ class Runner:
             # drain-aware pacing: once health flips for shutdown, throttle
             # sleeps shed instead of pinning workers through the drain
             draining_probe=lambda: not self.server.health.ok(),
-            host_fast_path=settings.host_fast_path,
             lease=self.lease_table,
         )
 

@@ -2,7 +2,7 @@
 
 v3 servicer: proto in -> service.should_rate_limit -> proto out. The client
 deadline is captured at this edge (context.time_remaining()) and propagated
-down the stack via utils/deadline.py, so the micro-batcher can drop expired
+down the stack via utils/deadline.py, so the batching layer can drop expired
 work before a device launch.
 
 Typed exceptions map onto distinct gRPC codes so Envoy's retry/fail-open

@@ -113,7 +113,6 @@ class RateLimitService:
         fallback=None,
         overload=None,
         draining_probe: Callable[[], bool] | None = None,
-        host_fast_path: bool = True,
         lease=None,
     ):
         """fallback: optional backends.fallback.FallbackLimiter — the
@@ -133,11 +132,11 @@ class RateLimitService:
         flipped for shutdown); used to skip throttle pacing sleeps so
         shutdown can never be pinned by sleeping workers.
 
-        host_fast_path: use the zero-object pipeline (compiled-matcher
-        resolve -> cache.do_limit_resolved) when both the config and the
-        cache support it (HOST_FAST_PATH). False pins the legacy
-        get_limit/do_limit path — the rollback knob, and the bench's
-        host_path_overhead_pct A/B arm.
+        The zero-object pipeline (compiled-matcher resolve ->
+        cache.do_limit_resolved) runs whenever the cache has
+        do_limit_resolved and the config has a compiled matcher; other
+        caches (memory, Redis, Memcache) take the per-object
+        get_limit/do_limit path.
 
         lease: optional backends.lease.LeaseTable (LEASE_ENABLED) — the
         frontend half of hierarchical quota leasing. Consulted BEFORE
@@ -145,13 +144,11 @@ class RateLimitService:
         coverable by live leases (or the over-limit cache) is answered
         entirely frontend-locally and never touches the device; misses
         ride the device path, which plans lease grants for them. Rides
-        the compiled-matcher pipeline only (host_fast_path)."""
+        the compiled-matcher pipeline only."""
         self._runtime = runtime
         self._cache = cache
-        self._lease = lease if host_fast_path else None
-        self._do_limit_resolved = (
-            getattr(cache, "do_limit_resolved", None) if host_fast_path else None
-        )
+        self._lease = lease
+        self._do_limit_resolved = getattr(cache, "do_limit_resolved", None)
         self._fallback = fallback
         self._overload = overload
         self._draining_probe = draining_probe

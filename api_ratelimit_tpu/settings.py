@@ -105,7 +105,9 @@ class Settings:
     # Explicit values must be a power of two; snapshots taken under a
     # different SLAB_WAYS rehash at restore, never reject.
     slab_ways: int = 0
-    tpu_batch_window: float = 0.0  # seconds; 0 = direct mode
+    # seconds; 0 = direct mode (each caller executes its own launch), > 0
+    # = the device-owner dispatch loop coalescing submits within the window
+    tpu_batch_window: float = 0.0
     tpu_batch_limit: int = 65536
     tpu_mesh_devices: int = 0  # 0 = single chip; N = shard slab over N devices
     tpu_use_pallas: bool = True
@@ -118,16 +120,6 @@ class Settings:
     # buckets trade padding waste for fewer compiled programs and a
     # faster precompile boot.
     tpu_buckets: str = ""
-    # zero-object host pipeline (compiled matcher -> row-block submit);
-    # false pins the legacy per-object path — the rollback knob
-    host_fast_path: bool = True
-    # persistent device-owner dispatch loop (backends/dispatch.py): one
-    # thread owns every launch AND readback, fed by per-frontend-thread
-    # submit rings, two batches double-buffered in flight. false falls
-    # back to the leader-collects micro-batcher — the rollback arm, same
-    # contract HOST_FAST_PATH set. Windowed mode only (TPU_BATCH_WINDOW
-    # > 0); direct mode ignores it.
-    dispatch_loop: bool = True
     # on-demand jax.profiler capture directory: GET /debug/profile?ms=N on
     # the debug port traces the device/owner loop into this directory
     # (TensorBoard/Perfetto-viewable). Empty (the default) leaves the
@@ -254,8 +246,7 @@ class Settings:
     # subsequent decisions for that (key, window) locally and settles
     # asynchronously — the hot head of a Zipf stream stops reaching the
     # device. false (the default) is the byte-identical rollback arm: the
-    # decide path is exactly the pre-lease pipeline (pinned by test, same
-    # discipline as HOST_FAST_PATH / DISPATCH_LOOP).
+    # decide path is exactly the pre-lease pipeline (pinned by test).
     lease_enabled: bool = False
     # adaptive grant sizing bounds: a fresh key starts at LEASE_MIN tokens,
     # doubles on renew-after-exhaustion up to LEASE_MAX, halves when a
@@ -282,7 +273,7 @@ class Settings:
     # per call when shm is unavailable (lease trailers, multi-address
     # failover clients, dead owner). false is the byte-identical
     # rollback arm — the wire and submit paths are exactly PR-10's
-    # (pinned by test, same discipline as HOST_FAST_PATH/DISPATCH_LOOP).
+    # (pinned by test).
     shm_rings: bool = True
     # control socket path; empty derives <SIDECAR_SOCKET>.shmctl for
     # unix sidecar addresses and disables shm for tcp://tls:// (no
@@ -349,8 +340,8 @@ class Settings:
     # journey flag, and (with LEASE_ENABLED) sketch-driven adaptive lease
     # pre-seeding. false is the byte-identical rollback arm: no sketch
     # array enters the launch pytree, so the traced program is exactly the
-    # pre-hotkeys one (pinned by test, same discipline as the multi_algo /
-    # DISPATCH_LOOP gates).
+    # pre-hotkeys one (pinned by test, same discipline as the multi_algo
+    # gate).
     hotkeys_enabled: bool = True
     # HOTKEY_K: how many ranked entries each drain reports
     hotkey_k: int = 16
@@ -385,7 +376,7 @@ class Settings:
     # stops scaling with the skew of the hottest shard. false is the
     # byte-identical rollback arm: the engine runs the original replicated
     # SPMD launch, same wire rows, same slab bytes, same verdicts (pinned
-    # by test, same discipline as HOST_FAST_PATH / DISPATCH_LOOP).
+    # by test).
     shard_routed_batching: bool = True
     # HOT_TIER_ENABLED: salt sketch-flagged hot keys across all shards
     # (ops/hashing.py hot_slice_fp) with a split-quota slice of
@@ -410,7 +401,7 @@ class Settings:
     # shares. false (the default) is the byte-identical rollback arm:
     # no coordinator is built, no wire op is served, the decide path is
     # exactly the pre-federation pipeline (pinned by test, same
-    # discipline as HOST_FAST_PATH / DISPATCH_LOOP / LEASE_ENABLED).
+    # discipline as LEASE_ENABLED).
     fed_enabled: bool = False
     # FED_SELF: this cluster's name in the membership (must appear in
     # FED_PEERS). Required when FED_ENABLED.
@@ -1028,8 +1019,6 @@ _FIELD_ENV: list[tuple[str, str, Callable]] = [
     ("tpu_use_pallas", "TPU_USE_PALLAS", _parse_bool),
     ("tpu_precompile", "TPU_PRECOMPILE", _parse_bool),
     ("tpu_buckets", "TPU_BUCKETS", str),
-    ("host_fast_path", "HOST_FAST_PATH", _parse_bool),
-    ("dispatch_loop", "DISPATCH_LOOP", _parse_bool),
     ("tpu_profile_dir", "TPU_PROFILE_DIR", str),
     ("journey_recorder_enabled", "JOURNEY_RECORDER_ENABLED", _parse_bool),
     ("journey_slow_ms", "JOURNEY_SLOW_MS", float),

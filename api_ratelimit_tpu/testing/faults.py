@@ -46,9 +46,9 @@ Sites wired in this codebase (backends/sidecar.py, backends/batcher.py):
     sidecar.dial            client: each dial of the sidecar address
     sidecar.submit          client: each SUBMIT attempt (before the send)
     sidecar.server.submit   server: each SUBMIT frame (before the engine)
-    batcher.submit          micro-batcher AND dispatch-loop: each submit
-                            before enqueue (the site is shared so one spec
-                            rehearses both DISPATCH_LOOP arms) — delay_ms
+    batcher.submit          direct-mode batcher AND dispatch loop: each
+                            submit before enqueue (the site is shared so
+                            one spec rehearses both modes) — delay_ms
                             stalls the caller (a wedged queue), queue_full
                             raises QueueFullError so chaos tests rehearse
                             overload shedding deterministically

@@ -13,7 +13,7 @@ A journey is the request's itinerary through the dispatch pipeline, as
 monotonic-ns stage timestamps:
 
     publish   frame published into the submit ring (or batcher queue)
-    take      owner/dispatcher thread took the frame out of the ring
+    take      owner thread took the frame out of the ring
     pack      frame gather into the padded launch operand began
     launch    async device dispatch returned
     redeem    blocking readback completed
@@ -23,8 +23,8 @@ The frontend half (publish) is recorded on the request thread; the owner
 half (take..scatter) rides the dispatch ticket across the thread hop and
 is merged after redemption — so a journey survives the thread (and, via
 the sidecar journey kind, the process) hops the async pipeline introduced.
-Both dispatch arms (DISPATCH_LOOP on/off) mark the same stage set, pinned
-by test.
+Direct mode (TPU_BATCH_WINDOW=0) marks the same stage set as the dispatch
+loop, pinned by test.
 
 Promotion flags: `slow` (duration over JOURNEY_SLOW_MS, or over the live
 p99 estimate when the knob is 0), `shed`, `deadline`, `fault`,
@@ -336,6 +336,6 @@ def note_flag(flag: str) -> None:
 
 
 def recording() -> bool:
-    """One-branch probe the owner/dispatcher threads use to decide whether
-    to stamp stage timestamps at all."""
+    """One-branch probe the owner and direct-mode threads use to decide
+    whether to stamp stage timestamps at all."""
     return _global_recorder is not None

@@ -7,14 +7,14 @@ have left?". The Python gRPC servicer only exposes
 that value the rest of the way — a contextvar holding the ABSOLUTE
 monotonic deadline, set by the transport for the duration of one request
 and readable by any layer on the same thread of execution (the service
-brain, the micro-batcher's submit path).
+brain, the batching layer's submit path).
 
 Why a contextvar and not a parameter: the deadline must cross the
 ``RateLimitCache.do_limit`` seam without changing every backend's
 signature, exactly like ``tracing.active_span()`` crosses it. Backends
-that don't care never look; the micro-batcher reads it at enqueue time and
-the dispatcher drops already-expired work before packing a device launch
-(backends/batcher.py).
+that don't care never look; the batching layer reads it at submit time and
+drops already-expired work before packing a device launch
+(backends/batcher.py, backends/dispatch.py).
 
 Monotonic clock only: deadlines are durations from "now", so they must be
 immune to wall-clock steps.

@@ -49,8 +49,6 @@ KNOB_NAMES = (
     "BENCH_TIERS",
     "BENCH_HOST_CPUS",
     "SLAB_WAYS",
-    "HOST_FAST_PATH",
-    "DISPATCH_LOOP",
     "SHM_RINGS",
     "LEASE_ENABLED",
     "HOTKEYS_ENABLED",

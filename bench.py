@@ -14,7 +14,7 @@ Two tiers:
     runs the slab program, and ships 1 byte/decision back.
 
   * SERVICE (configs[0..3]): the full host path end to end —
-    should_rate_limit -> config trie -> fingerprints -> micro-batcher ->
+    should_rate_limit -> config trie -> fingerprints -> dispatch loop ->
     device -> decision math — driven by concurrent threads, measuring
     per-request p99 alongside throughput: flat per-second rule, nested
     tree, dual-window (second+hour), and near-limit with the local
@@ -1651,7 +1651,7 @@ _HOST_STAGE_HISTOGRAMS = (
     ("response_ns", "ratelimit.host.response_ms"),
 )
 
-# The device-owner dispatch loop's per-cycle stages (DISPATCH_LOOP on),
+# The device-owner dispatch loop's per-cycle stages (windowed mode),
 # in NANOSECONDS: publish -> take ring wait, frame gather into the padded
 # operand, async launch dispatch, blocking readback + verdict scatter.
 # Same runtime histograms GET /metrics renders (backends/dispatch.py).
@@ -1746,19 +1746,15 @@ def _build_service(
     yaml_text: str,
     telemetry: bool,
     on_tpu: bool = False,
-    host_fast_path: bool = True,
-    dispatch_loop: bool = True,
     lease: bool = False,
     hotkey_lanes: int = 0,
 ):
     """One service stack for a scenario; telemetry=False builds the same
     stack with no stats scope on the backend (the A/B for recording
-    overhead); host_fast_path=False pins the legacy per-object host path
-    (the host_path_overhead_pct A/B arm); dispatch_loop=False pins the
-    leader-collects batcher (the dispatch_loop_overhead_pct A/B arm);
-    lease=True wires a LeaseTable (LEASE_ENABLED production posture — the
-    lease_zipf scenario's primary arm); hotkey_lanes>0 arms the in-kernel
-    heavy-hitter sketch (the hotkeys tier's sketch→lease pre-seed arm).
+    overhead); lease=True wires a LeaseTable (LEASE_ENABLED production
+    posture — the lease_zipf scenario's primary arm); hotkey_lanes>0 arms
+    the in-kernel heavy-hitter sketch (the hotkeys tier's sketch→lease
+    pre-seed arm).
     Returns (service, cache, store)."""
     import random
 
@@ -1813,7 +1809,6 @@ def _build_service(
         # TPU_PRECOMPILE posture; first-touch compiles otherwise ride the
         # warmup's tail and pollute the first timed samples)
         precompile=True,
-        dispatch_loop=dispatch_loop,
         lease_table=lease_table,
         hotkey_lanes=hotkey_lanes,
     )
@@ -1822,7 +1817,6 @@ def _build_service(
         cache=cache,
         stats_scope=store.scope("ratelimit").scope("service"),
         time_source=RealTimeSource(),
-        host_fast_path=host_fast_path,
         lease=lease_table,
     )
     return service, cache, store
@@ -1834,8 +1828,6 @@ def bench_service(
     on_tpu: bool,
     measure_telemetry_overhead: bool = False,
     measure_snapshot_overhead: bool = False,
-    measure_host_path_overhead: bool = False,
-    measure_dispatch_overhead: bool = False,
     measure_tracing_overhead: bool = False,
     measure_lease: bool = False,
 ) -> dict:
@@ -1855,16 +1847,6 @@ def bench_service(
     regression" budget for the quiesce-and-copy design (the periodic
     device-side copy rides the stream; only the D2H drain and file write
     run on the snapshot thread).
-
-    measure_host_path_overhead: drive the same scenario once more with
-    HOST_FAST_PATH pinned off (legacy get_limit walk + per-object
-    do_limit) and record the legacy rate + host_path_overhead_pct — what
-    the pre-vectorization host path costs relative to the shipped one.
-
-    measure_dispatch_overhead: drive the same scenario once more with
-    DISPATCH_LOOP pinned off (leader-collects batcher, the rollback arm)
-    and record rate_leader_collects + dispatch_loop_overhead_pct — what
-    the pre-loop dispatch path gives up relative to the shipped one.
 
     measure_tracing_overhead: drive the same scenario once more with the
     tracer (RecordingTracer, every request spanned) AND the journey
@@ -2019,45 +2001,6 @@ def bench_service(
         if rate_off > 0:
             result["telemetry_overhead_pct"] = round(
                 (1.0 - result["rate"] / rate_off) * 100.0, 2
-            )
-    if measure_host_path_overhead:
-        service_l, cache_l, _store_l = _build_service(
-            config_key, yaml_text, telemetry=True, on_tpu=on_tpu,
-            host_fast_path=False,
-        )
-        for r in reqs[:32]:
-            service_l.should_rate_limit(r)
-        total_l, elapsed_l, _lat_l = _drive_service(
-            service_l, reqs, n_threads, per_thread
-        )
-        cache_l.close()
-        rate_l = total_l * decisions_per_request / elapsed_l
-        result["rate_legacy_host_path"] = round(rate_l)
-        if result["rate"] > 0:
-            # how much of the shipped rate the legacy host path gives up
-            result["host_path_overhead_pct"] = round(
-                (1.0 - rate_l / result["rate"]) * 100.0, 2
-            )
-    if measure_dispatch_overhead:
-        service_d, cache_d, _store_d = _build_service(
-            config_key, yaml_text, telemetry=True, on_tpu=on_tpu,
-            dispatch_loop=False,
-        )
-        for r in reqs[:32]:
-            service_d.should_rate_limit(r)
-        total_d, elapsed_d, lat_d = _drive_service(
-            service_d, reqs, n_threads, per_thread
-        )
-        cache_d.close()
-        rate_d = total_d * decisions_per_request / elapsed_d
-        result["rate_leader_collects"] = round(rate_d)
-        result["p99_leader_collects_ms"] = round(
-            float(np.percentile(lat_d, 99)), 3
-        )
-        if result["rate"] > 0:
-            # how much of the shipped rate the pre-loop dispatch gives up
-            result["dispatch_loop_overhead_pct"] = round(
-                (1.0 - rate_d / result["rate"]) * 100.0, 2
             )
     if measure_tracing_overhead:
         from api_ratelimit_tpu.tracing import (
@@ -2221,7 +2164,7 @@ def bench_engine_sharded(n_devices: int, on_tpu: bool) -> dict:
     # PIPELINED compacted mode — what the backend's double-buffered
     # dispatcher actually runs (backends/tpu.py): launch k+1 (routing + H2D
     # + dispatch) overlaps collect k (readback + unscatter), bounded at two
-    # in flight like MicroBatcher's max_inflight default.
+    # in flight like the dispatch loop's double buffer.
     t0 = time.perf_counter()
     token = engine.launch_after_compact(slices[1][0], cap=0xFFFF, min_bucket=bucket)
     for b in slices[1][1:]:
@@ -2657,7 +2600,7 @@ def bench_sidecar(
 ) -> dict:
     """The sidecar aggregation story, measured (VERDICT r2 weak #3): N
     frontend PROCESSES -> one sidecar -> one slab. The sidecar's
-    micro-batcher coalesces across every frontend, so aggregate throughput
+    dispatch loop coalesces across every frontend, so aggregate throughput
     should RISE with frontend count while per-request p99 holds — the claim
     backends/sidecar.py:3-16 makes, now with a number attached.
 
@@ -3947,16 +3890,6 @@ def main() -> None:
                 # the durability-cost A/B rides the same scenario: an
                 # aggressive 100ms snapshot cadence must not move p99
                 measure_snapshot_overhead=(
-                    key == "flat_per_second" and left() > 100
-                ),
-                # legacy-host-path A/B: records the vectorization win
-                # (host_path_overhead_pct) in every artifact
-                measure_host_path_overhead=(
-                    key == "flat_per_second" and left() > 100
-                ),
-                # leader-collects A/B: records the dispatch-loop win
-                # (dispatch_loop_overhead_pct) in every artifact
-                measure_dispatch_overhead=(
                     key == "flat_per_second" and left() > 100
                 ),
                 # journey tracing A/B: tracer + flight recorder on vs the

@@ -537,21 +537,46 @@ class TestClosedBatcherIsCacheError:
     """Satellite: a submit racing shutdown must surface as a counted
     backend failure (CacheError), not an unhandled RuntimeError 500."""
 
+    @staticmethod
+    def _closed_engine(window):
+        import numpy as np
+
+        eng = SlabDeviceEngine(
+            time_source=FakeTimeSource(1_000_000),
+            n_slots=1 << 10,
+            use_pallas=False,
+            batch_window_seconds=window,
+            buckets=(8,),
+            max_batch=8,
+        )
+        eng.close()
+        block = np.zeros((6, 1), dtype=np.uint32)
+        block[2] = 1
+        return eng, block
+
     def test_direct_mode(self):
+        import numpy as np
+
         from api_ratelimit_tpu.backends.batcher import MicroBatcher
 
-        b = MicroBatcher(lambda items: [0] * len(items), window_seconds=0.0)
+        b = MicroBatcher(lambda blocks: np.zeros(1, dtype=np.uint32))
         b.close()
         with pytest.raises(CacheError, match="batcher is closed"):
-            b.submit([1])
+            b.submit(np.zeros((6, 1), dtype=np.uint32))
 
     def test_windowed_mode(self):
-        from api_ratelimit_tpu.backends.batcher import MicroBatcher
+        """Windowed mode submits ride the dispatch loop: once the engine
+        is closed they answer CacheError the same way."""
+        eng, block = self._closed_engine(0.001)
+        assert eng.dispatch_loop is not None
+        with pytest.raises(CacheError, match="dispatch loop is closed"):
+            eng.submit_rows(block)
 
-        b = MicroBatcher(lambda items: [0] * len(items), window_seconds=0.001)
-        b.close()
+    def test_direct_mode_engine(self):
+        eng, block = self._closed_engine(0.0)
+        assert eng.dispatch_loop is None
         with pytest.raises(CacheError, match="batcher is closed"):
-            b.submit([1])
+            eng.submit_rows(block)
 
 
 class TestFullStackAcceptance:

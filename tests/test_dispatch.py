@@ -1,7 +1,8 @@
 """Persistent device-owner dispatch loop (backends/dispatch.py): submit-ring
 mechanics, double-buffered launch overlap, drain/close with tickets parked
-in both in-flight buffers, deadline drops at ring take time, overload
-parity with the leader-collects arm, and the dispatch.launch chaos site.
+in both in-flight buffers, deadline drops at ring take time, the
+dispatch.launch chaos site, and engine-level parity with direct mode
+(TPU_BATCH_WINDOW=0).
 """
 
 import threading
@@ -466,26 +467,38 @@ class TestDispatchLoop:
             loop.close()
 
 
+def _mode_engine(loop, **kwargs):
+    """A small engine in windowed mode (loop=True: the dispatch loop) or
+    direct mode (loop=False: TPU_BATCH_WINDOW=0)."""
+    from api_ratelimit_tpu.backends.tpu import SlabDeviceEngine
+
+    kwargs.setdefault("time_source", FakeTimeSource(700_000))
+    return SlabDeviceEngine(
+        n_slots=1 << 12,
+        use_pallas=False,
+        batch_window_seconds=0.002 if loop else 0.0,
+        buckets=(8, 128),
+        max_batch=128,
+        **kwargs,
+    )
+
+
+def _rows(fps, hits=1, limit=1_000_000, divider=60):
+    block = np.zeros((6, len(fps)), dtype=np.uint32)
+    block[0] = fps
+    block[2] = hits
+    block[3] = limit
+    block[4] = divider
+    return block
+
+
 class TestEngineParity:
-    """Row-block results must be byte-identical between the dispatch-loop
-    and leader-collects arms (acceptance criterion), and both arms must
-    answer saturation/shed identically."""
+    """Row-block results must be byte-identical between the dispatch loop
+    and direct mode, and both must answer saturation identically."""
 
     @staticmethod
-    def _engine(dispatch_loop, **kwargs):
-        from api_ratelimit_tpu.backends.tpu import SlabDeviceEngine
-
-        ts = FakeTimeSource(700_000)
-        return SlabDeviceEngine(
-            time_source=ts,
-            n_slots=1 << 12,
-            use_pallas=False,
-            batch_window_seconds=0.002,
-            buckets=(8, 128),
-            max_batch=128,
-            dispatch_loop=dispatch_loop,
-            **kwargs,
-        )
+    def _engine(loop, **kwargs):
+        return _mode_engine(loop, **kwargs)
 
     def test_row_block_results_byte_identical_across_arms(self):
         import random
@@ -554,8 +567,8 @@ class TestEngineParity:
 
     def test_full_occupancy_parity(self):
         """There is no saturation shed anymore: past 100% live occupancy
-        both arms keep answering (the set scan evicts in-kernel), and the
-        answers stay byte-identical across arms."""
+        the dispatch loop and direct mode keep answering (the set scan
+        evicts in-kernel), and the answers stay byte-identical."""
         from api_ratelimit_tpu.backends.tpu import SlabDeviceEngine
 
         outs = {}
@@ -564,10 +577,9 @@ class TestEngineParity:
                 time_source=FakeTimeSource(700_000),
                 n_slots=128,
                 use_pallas=False,
-                batch_window_seconds=0.002,
+                batch_window_seconds=0.002 if arm else 0.0,
                 buckets=(8,),
                 max_batch=8,
-                dispatch_loop=arm,
             )
             got = []
             try:
@@ -587,3 +599,177 @@ class TestEngineParity:
                 eng.close()
             outs[arm] = got
         assert outs[True] == outs[False]
+
+
+MODES = pytest.mark.parametrize("loop", [True, False], ids=["loop", "direct"])
+
+
+class TestEngineModes:
+    """Engine-level behaviour both batching modes owe their callers: the
+    dispatch loop (windowed) and direct mode (TPU_BATCH_WINDOW=0)."""
+
+    @MODES
+    def test_mode_follows_the_window(self, loop):
+        eng = _mode_engine(loop)
+        try:
+            assert (eng.dispatch_loop is not None) == loop
+        finally:
+            eng.close()
+
+    @MODES
+    def test_closed_engine_raises_cache_error(self, loop):
+        eng = _mode_engine(loop)
+        eng.close()
+        with pytest.raises(CacheError, match="closed"):
+            eng.submit_rows(_rows([5]))
+        assert eng.health_snapshot()["decisions"] == 0
+
+    @MODES
+    def test_drain_refuses_then_finishes(self, loop):
+        """drain() returns only once every admitted submit has launched:
+        the decision count read right after it never moves again, and it
+        equals the submits that were answered."""
+        eng = _mode_engine(loop)
+        answered = []
+        lock = threading.Lock()
+        started = threading.Barrier(5)
+
+        def worker(tid):
+            block = _rows([100 + tid])
+            started.wait(5.0)
+            n = 0
+            while True:
+                try:
+                    eng.submit_rows(block)
+                except CacheError:
+                    break
+                n += 1
+            with lock:
+                answered.append(n)
+
+        threads = [threading.Thread(target=worker, args=(t,)) for t in range(4)]
+        for t in threads:
+            t.start()
+        started.wait(5.0)
+        time.sleep(0.05)
+        eng.drain()
+        at_drain = eng.health_snapshot()["decisions"]
+        for t in threads:
+            t.join(10.0)
+        try:
+            assert len(answered) == 4
+            assert sum(answered) > 0
+            assert eng.health_snapshot()["decisions"] == at_drain
+            assert at_drain == sum(answered)
+            with pytest.raises(CacheError):
+                eng.submit_rows(_rows([1]))
+        finally:
+            eng.close()
+
+    @MODES
+    def test_submit_rows_registers_lease_ops(self, loop):
+        from api_ratelimit_tpu.backends.lease import LeaseOps
+
+        eng = _mode_engine(loop)
+        try:
+            block = _rows([11, 12], hits=(1, 9))
+            ops = LeaseOps(grants=[(1, 8, 60, 30)], settles=[])
+            afters = eng.submit_rows(block, lease_ops=ops)
+            assert afters.tolist() == [1, 9]
+            # one liability, its 8 tokens unsettled
+            assert eng.lease_registry.outstanding() == (1, 8)
+        finally:
+            eng.close()
+
+    @MODES
+    def test_scratch_block_reuse_under_concurrency(self, loop):
+        """Each thread rewrites ONE scratch block between submits (keys
+        alternate): counters stay exact per key, so no submit ever read a
+        block its caller had already rewritten."""
+        eng = _mode_engine(loop)
+        per_thread = 20
+        got: dict = {}
+
+        def worker(tid):
+            scratch = _rows([0])
+            seen = []
+            for i in range(per_thread):
+                scratch[0, 0] = 1000 + 2 * tid + (i & 1)
+                seen.append(int(eng.submit_rows(scratch)[0]))
+            got[tid] = seen
+
+        threads = [threading.Thread(target=worker, args=(t,)) for t in range(4)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(10.0)
+        eng.close()
+        want = [i // 2 + 1 for i in range(per_thread)]
+        assert got == {tid: want for tid in range(4)}
+
+    @MODES
+    def test_batcher_submit_fault_site(self, loop):
+        from api_ratelimit_tpu.testing.faults import FaultInjector
+
+        injector = FaultInjector.from_spec("batcher.submit:queue_full:1")
+        eng = _mode_engine(loop, fault_injector=injector)
+        try:
+            with pytest.raises(QueueFullError, match="injected"):
+                eng.submit_rows(_rows([3]))
+            assert eng.health_snapshot()["decisions"] == 0
+            assert injector.fired() == {"batcher.submit:queue_full": 1}
+        finally:
+            eng.close()
+
+    @MODES
+    def test_item_verb_matches_row_verb(self, loop):
+        from api_ratelimit_tpu.backends.tpu import _Item
+
+        eng_items = _mode_engine(loop)
+        eng_rows = _mode_engine(loop)
+        try:
+            items = [_Item(fp, 1, 50, 60, 0) for fp in (7, 8, 7, 7)]
+            by_items = eng_items.submit(items)
+            by_rows = eng_rows.submit_rows(_rows([7, 8, 7, 7], limit=50))
+            assert by_items == by_rows.tolist() == [1, 1, 2, 3]
+        finally:
+            eng_items.close()
+            eng_rows.close()
+
+    @MODES
+    def test_submit_block_answers_owned_arrays(self, loop):
+        """block_mode (the sidecar server): submit_block results belong to
+        the caller and survive the same thread's next submit."""
+        eng = _mode_engine(loop, block_mode=True)
+        try:
+            first = eng.submit_block(_rows([21, 22]))
+            second = eng.submit_block(_rows([21, 22]))
+            assert first.tolist() == [1, 1]
+            assert second.tolist() == [2, 2]
+            with pytest.raises(RuntimeError, match="block_mode"):
+                eng.submit([])
+        finally:
+            eng.close()
+
+    @MODES
+    def test_queue_wait_lands_in_its_mode_histogram(self, loop):
+        """Direct mode records ratelimit.batcher.queue_wait_ms, the loop
+        ratelimit.dispatch.ring_wait_ms; the batcher gauges are exported
+        in both modes."""
+        from api_ratelimit_tpu.stats import Store, TestSink
+
+        store = Store(TestSink())
+        eng = _mode_engine(loop, scope=store.scope("ratelimit"))
+        try:
+            for _ in range(3):
+                eng.submit_rows(_rows([31]))
+        finally:
+            eng.close()
+        store.flush()
+        snap = store.metrics_snapshot()
+        hists = snap["histograms"]
+        ring = hists.get("ratelimit.dispatch.ring_wait_ms", {}).get("count", 0)
+        direct = hists["ratelimit.batcher.queue_wait_ms"]["count"]
+        assert (ring, direct) == ((3, 0) if loop else (0, 3))
+        assert "ratelimit.batcher.queue_depth" in snap["gauges"]
+        assert "ratelimit.batcher.inflight" in snap["gauges"]

@@ -1,18 +1,16 @@
 """Host-path vectorization pins: bucket-ladder precompile (no
 first-request JIT compile), the zero-object row pipeline
-(do_limit_resolved vs do_limit equivalence), the batcher's row ring
-copy-before-return contract, and the host-stage histograms the bench's
+(do_limit_resolved vs do_limit equivalence), scratch-block reuse in
+both batching modes, and the host-stage histograms the bench's
 host_split block reads."""
 
 from __future__ import annotations
 
 import threading
-import time
 
 import numpy as np
 import pytest
 
-from api_ratelimit_tpu.backends.batcher import MicroBatcher
 from api_ratelimit_tpu.backends.tpu import SlabDeviceEngine, TpuRateLimitCache, _Item
 from api_ratelimit_tpu.limiter.base_limiter import BaseRateLimiter
 from api_ratelimit_tpu.models import Code, Descriptor, RateLimitRequest, Unit
@@ -241,9 +239,9 @@ class TestZeroObjectPipeline:
             cache_b.close()
 
     def test_service_uses_fast_path_and_flags_work(self):
-        """Through RateLimitService: the resolved path is taken (legacy
-        do_limit untouched), and host_fast_path=False pins the legacy
-        path — the rollback knob."""
+        """Through RateLimitService: a cache with do_limit_resolved takes
+        the resolved path (do_limit untouched); a cache without it takes
+        do_limit — and both answer the same."""
         from api_ratelimit_tpu.service.ratelimit import RateLimitService
         from api_ratelimit_tpu.utils.timeutil import RealTimeSource
 
@@ -261,6 +259,16 @@ class TestZeroObjectPipeline:
             def add_update_callback(self, cb):
                 pass
 
+        class PerObjectOnly:
+            """The per-object cache surface alone (as the memory, Redis
+            and Memcache backends expose it)."""
+
+            def __init__(self, inner):
+                self.do_limit = inner.do_limit
+                self.flush = inner.flush
+                self.close = inner.close
+
+        answers = {}
         for fast in (True, False):
             ts = FakeTimeSource(1_000_000)
             base = BaseRateLimiter(ts, near_limit_ratio=0.8)
@@ -281,22 +289,27 @@ class TestZeroObjectPipeline:
             store = Store(TestSink())
             service = RateLimitService(
                 runtime=StaticRuntime(),
-                cache=cache,
+                cache=cache if fast else PerObjectOnly(cache),
                 stats_scope=store.scope("ratelimit").scope("service"),
                 time_source=RealTimeSource(),
-                host_fast_path=fast,
             )
             request = RateLimitRequest(
                 domain="d", descriptors=(Descriptor.of(("api", "u")),)
             )
-            code, statuses, _ = service.should_rate_limit(request)
-            assert code == Code.OK
-            assert statuses[0].current_limit.requests_per_unit == 4
+            got = []
+            for _ in range(5):
+                code, statuses, _ = service.should_rate_limit(request)
+                assert statuses[0].current_limit.requests_per_unit == 4
+                got.append((code, statuses[0].code, statuses[0].limit_remaining))
             if fast:
-                assert calls == {"resolved": 1, "legacy": 0}
+                assert calls == {"resolved": 5, "legacy": 0}
             else:
-                assert calls == {"resolved": 0, "legacy": 1}
+                assert calls == {"resolved": 0, "legacy": 5}
+            answers[fast] = got
             cache.close()
+        assert answers[True] == answers[False]
+        assert answers[True][0][0] == Code.OK
+        assert answers[True][-1][0] == Code.OVER_LIMIT
 
     def test_host_stage_histograms_recorded(self):
         """ratelimit.host.{key_compose_ms,response_ms} and
@@ -352,80 +365,23 @@ class TestZeroObjectPipeline:
 
 
 class TestRowRing:
-    def test_ring_copies_before_submit_returns(self):
-        """The caller may reuse its scratch block the moment submit()
-        returns: mutate the submitted block while the batch is gated
-        mid-flight — results must reflect the ORIGINAL rows."""
-        gate = threading.Event()
-        seen = []
-
-        def launch(blocks):
-            seen.extend(np.array(b) for b in blocks)
-            return [np.array(b) for b in blocks]
-
-        def collect(token):
-            gate.wait(5.0)
-            return np.concatenate([b[2] for b in token])  # the hits row
-
-        b = MicroBatcher(
-            lambda blocks: collect(launch(blocks)),
-            window_seconds=0.005,
-            max_batch=64,
-            execute_launch=launch,
-            execute_collect=collect,
-            block_mode=True,
-            arena_rows=128,
-        )
-        scratch = np.zeros((6, 2), dtype=np.uint32)
-        scratch[2] = (7, 9)
-        out = []
-        t = threading.Thread(target=lambda: out.append(b.submit(scratch)))
-        t.start()
-        # wait until the rows are enqueued (copied into the ring), then
-        # clobber the caller's scratch before allowing the collect
-        deadline = time.monotonic() + 2.0
-        while not seen and time.monotonic() < deadline:
-            time.sleep(0.002)
-        scratch[:] = 0xFFFF
-        gate.set()
-        t.join(5.0)
-        b.close()
-        assert out and out[0].tolist() == [7, 9]
-
-    def test_ring_overflow_falls_back_to_owned_copies(self):
-        """Blocks past the ring capacity still submit correctly (the
-        overflow path copies instead of failing)."""
-        b = MicroBatcher(
-            lambda blocks: np.concatenate([np.asarray(blk)[2] for blk in blocks]),
-            window_seconds=0.002,
-            max_batch=4096,
-            block_mode=True,
-            arena_rows=8,  # tiny ring: most submits overflow
-        )
-        outs = []
-        lock = threading.Lock()
-
-        def one(i):
-            block = np.zeros((6, 3), dtype=np.uint32)
-            block[2] = (i, i + 100, i + 200)
-            got = b.submit(block)
-            with lock:
-                outs.append((i, list(got)))
-
-        threads = [threading.Thread(target=one, args=(i,)) for i in range(16)]
-        for t in threads:
-            t.start()
-        for t in threads:
-            t.join(5.0)
-        b.close()
-        assert len(outs) == 16
-        for i, got in outs:
-            assert got == [i, i + 100, i + 200]
+    """Callers hand in a thread-local scratch block they reuse on the next
+    request: the dispatch loop copies it into the caller's submit ring,
+    and direct mode executes it before submit returns."""
 
     def test_engine_scratch_reuse_is_safe_under_concurrency(self):
         """do_limit_resolved from many threads over the windowed engine:
         each caller's counts are exact (thread-local scratch + ring copy
         never cross-contaminate)."""
+        self._scratch_reuse(batch_window_seconds=0.002)
+
+    def test_engine_scratch_reuse_is_safe_in_direct_mode(self):
+        """The same over direct mode (TPU_BATCH_WINDOW=0): callers take
+        turns at the direct lock, each executing its own scratch."""
+        self._scratch_reuse(batch_window_seconds=0.0)
+
+    @staticmethod
+    def _scratch_reuse(batch_window_seconds):
         cfg = _load_cfg(
             "domain: d\n"
             "descriptors:\n"
@@ -437,10 +393,13 @@ class TestRowRing:
         cache = TpuRateLimitCache(
             base,
             n_slots=1 << 12,
-            batch_window_seconds=0.002,
+            batch_window_seconds=batch_window_seconds,
             buckets=(8, 128),
             max_batch=128,
             use_pallas=False,
+        )
+        assert (cache.engine.dispatch_loop is None) == (
+            batch_window_seconds == 0
         )
         per_thread = 25
         remaining: dict[int, list] = {}

@@ -48,7 +48,7 @@ def _clean_globals():
     set_global_recorder(None)
 
 
-def make_engine(window=0.002, dispatch_loop=True, block_mode=False):
+def make_engine(window=0.002, block_mode=False):
     return SlabDeviceEngine(
         time_source=RealTimeSource(),
         n_slots=1 << 12,
@@ -57,7 +57,6 @@ def make_engine(window=0.002, dispatch_loop=True, block_mode=False):
         buckets=(8, 64),
         use_pallas=False,
         block_mode=block_mode,
-        dispatch_loop=dispatch_loop,
     )
 
 
@@ -156,14 +155,13 @@ class TestJourneyRecorder:
 
 
 class TestDispatchArmParity:
-    """Both dispatch arms (DISPATCH_LOOP on/off) must record the SAME
-    journey stage set — the acceptance pin for the tentpole's 'both arms
-    produce the same journey stages' contract."""
+    """Both batching modes — the dispatch loop (windowed) and direct mode
+    (TPU_BATCH_WINDOW=0) — must record the SAME journey stage set."""
 
-    def _journey_stages(self, dispatch_loop: bool) -> set:
+    def _journey_stages(self, window: float) -> set:
         rec = JourneyRecorder(slow_ms=1e9)
         set_global_recorder(rec)
-        engine = make_engine(window=0.002, dispatch_loop=dispatch_loop)
+        engine = make_engine(window=window)
         try:
             j = rec.begin("request")
             engine.submit_rows(block())
@@ -174,10 +172,10 @@ class TestDispatchArmParity:
             set_global_recorder(None)
 
     def test_stage_sets_identical_across_arms(self):
-        loop_stages = self._journey_stages(dispatch_loop=True)
-        batcher_stages = self._journey_stages(dispatch_loop=False)
+        loop_stages = self._journey_stages(window=0.002)
+        direct_stages = self._journey_stages(window=0.0)
         assert loop_stages == set(STAGES)
-        assert batcher_stages == set(STAGES)
+        assert direct_stages == set(STAGES)
 
     def test_direct_mode_records_full_stage_set(self):
         rec = JourneyRecorder(slow_ms=1e9)
@@ -198,7 +196,7 @@ class TestConnectedTrace:
         acceptance shape, in-process arm)."""
         tracer = RecordingTracer()
         set_global_tracer(tracer)
-        engine = make_engine(window=0.002, dispatch_loop=True)
+        engine = make_engine(window=0.002)
         try:
             request_span = tracer.start_span("request")
             with request_span, activate(request_span):
@@ -221,7 +219,7 @@ class TestConnectedTrace:
     def test_batch_span_links_every_coalesced_request(self):
         tracer = RecordingTracer()
         set_global_tracer(tracer)
-        engine = make_engine(window=0.01, dispatch_loop=True)
+        engine = make_engine(window=0.01)
         barrier = threading.Barrier(3)
         span_ids = []
         lock = threading.Lock()
@@ -256,7 +254,7 @@ class TestConnectedTrace:
     def test_untraced_requests_build_no_spans(self):
         tracer = RecordingTracer()
         set_global_tracer(tracer)
-        engine = make_engine(window=0.002, dispatch_loop=True)
+        engine = make_engine(window=0.002)
         try:
             engine.submit_rows(block())
         finally:
@@ -266,7 +264,7 @@ class TestConnectedTrace:
 
 class TestSidecarWireTrace:
     def _stack(self, tmp_path, fault_injector=None, **client_kwargs):
-        engine = make_engine(window=0.002, dispatch_loop=True, block_mode=True)
+        engine = make_engine(window=0.002, block_mode=True)
         path = str(tmp_path / "sidecar.sock")
         server = SlabSidecarServer(path, engine)
         client = SidecarEngineClient(
@@ -411,7 +409,6 @@ class TestDispatchTelemetry:
             buckets=(8, 64),
             use_pallas=False,
             scope=store.scope("ratelimit"),
-            dispatch_loop=True,
         )
         try:
             span = tracer.start_span("request")
@@ -443,7 +440,6 @@ class TestDispatchTelemetry:
             buckets=(8, 64),
             use_pallas=False,
             fault_injector=injector,
-            dispatch_loop=True,
         )
         injector.configure("dispatch.launch:error:1.0")
         try:

@@ -264,8 +264,7 @@ class TestServiceLeaseLocal:
     def test_byte_identical_to_lease_off_arm(self):
         """The LEASE_ENABLED=false rollback pin: a sequential stream makes
         the SAME decisions leased and unleased — reservation leasing is an
-        exact continuation of the device counter (same discipline as the
-        HOST_FAST_PATH / DISPATCH_LOOP rollback arms)."""
+        exact continuation of the device counter."""
         ts_on, ts_off = FakeTimeSource(1_000_000), FakeTimeSource(1_000_000)
         svc_on, cache_on, _, _ = _stack(ts_on, lease=True)
         svc_off, cache_off, _, _ = _stack(ts_off, lease=False)
@@ -850,18 +849,27 @@ class TestRunnerIntegration:
 
 class TestDispatchLoopArm:
     def test_leases_ride_the_dispatch_loop(self):
-        """Windowed mode (DISPATCH_LOOP): grant riders travel the submit
-        rings like any other frame and the liability registers from the
-        ticket's verdicts."""
+        """Windowed mode (TPU_BATCH_WINDOW > 0): grant riders travel the
+        submit rings like any other frame and the liability registers from
+        the ticket's verdicts."""
+        self._leases_ride(batch_window_seconds=0.0002)
+
+    def test_leases_ride_direct_mode(self):
+        """Direct mode (TPU_BATCH_WINDOW=0): the caller's own launch
+        carries the grant riders and registers the liability."""
+        self._leases_ride(batch_window_seconds=0.0)
+
+    @staticmethod
+    def _leases_ride(batch_window_seconds):
         ts = FakeTimeSource(1_000_000)
         engine = SlabDeviceEngine(
             time_source=ts,
             n_slots=1 << 10,
             use_pallas=False,
             buckets=(128,),
-            batch_window_seconds=0.0002,
-            dispatch_loop=True,
+            batch_window_seconds=batch_window_seconds,
         )
+        assert (engine.dispatch_loop is None) == (batch_window_seconds == 0)
         svc, cache, table, store = _stack(ts, engine=engine)
         try:
             for _ in range(40):

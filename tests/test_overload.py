@@ -8,9 +8,9 @@ throttle sleep instead of pinning workers.
 
 from __future__ import annotations
 
-import threading
 import time
 
+import numpy as np
 import pytest
 
 from api_ratelimit_tpu.backends.batcher import MicroBatcher
@@ -149,51 +149,26 @@ class TestDeadlineContext:
         assert time_remaining() is None
 
 
+def _zeros_exec(blocks):
+    """Direct-mode executor stub: one 0 verdict per row."""
+    return np.zeros(sum(b.shape[1] for b in blocks), dtype=np.uint32)
+
+
+def _one_row():
+    return np.zeros((6, 1), dtype=np.uint32)
+
+
 class TestBatcherDeadline:
     def test_direct_mode_expired_sheds_before_execute(self):
         executed = []
-        b = MicroBatcher(lambda items: executed.append(items) or [0] * len(items))
+        b = MicroBatcher(lambda blocks: executed.append(blocks) or _zeros_exec(blocks))
         with deadline_scope(-0.001):
             with pytest.raises(DeadlineExceededError):
-                b.submit([1])
+                b.submit(_one_row())
         assert executed == []
         assert b.deadline_drops == 1
         # without a deadline the same submit executes
-        assert b.submit([1]) == [0]
-
-    def test_windowed_expired_items_never_reach_a_launch(self):
-        """The tentpole invariant: an expired request's items are dropped
-        at take time — they resolve as shed and never consume batch
-        slots — while fresh requests in the same window still execute."""
-        launched: list = []
-
-        def execute(items):
-            launched.extend(items)
-            return [0] * len(items)
-
-        b = MicroBatcher(execute, window_seconds=0.02)
-        results = {}
-
-        def worker(name, remaining):
-            def run():
-                try:
-                    with deadline_scope(remaining):
-                        results[name] = b.submit([name])
-                except DeadlineExceededError:
-                    results[name] = "expired"
-
-            t = threading.Thread(target=run)
-            t.start()
-            return t
-
-        threads = [worker("dead", -0.001), worker("live", None)]
-        for t in threads:
-            t.join(10.0)
-        b.close()
-        assert results["dead"] == "expired"
-        assert results["live"] == [0]
-        assert launched == ["live"]
-        assert b.deadline_drops == 1
+        assert b.submit(_one_row()).tolist() == [0]
 
     def test_service_sheds_expired_before_cache(self, test_store):
         store, _ = test_store
@@ -215,46 +190,11 @@ class TestBatcherDeadline:
 
 
 class TestQueueBound:
-    def test_max_queue_sheds_instantly_while_stalled(self):
-        """With the executor wedged, submits past max_queue answer
-        immediately with QueueFullError instead of queueing unbounded."""
-        start = threading.Event()
-        release = threading.Event()
-
-        def execute(items):
-            start.set()
-            assert release.wait(10.0)
-            return [0] * len(items)
-
-        b = MicroBatcher(execute, window_seconds=0.005, max_queue=2)
-        stalled = threading.Thread(target=lambda: b.submit(["a"]))
-        stalled.start()
-        assert start.wait(5.0)  # dispatcher is now wedged in execute()
-        waiters = [
-            threading.Thread(target=lambda: b.submit(["b"])),
-            threading.Thread(target=lambda: b.submit(["c"])),
-        ]
-        for t in waiters:
-            t.start()
-        deadline = time.monotonic() + 5.0
-        while b.queue_depth < 2 and time.monotonic() < deadline:
-            time.sleep(0.001)
-        assert b.queue_depth == 2
-        t0 = time.monotonic()
-        with pytest.raises(QueueFullError):
-            b.submit(["d"])
-        assert time.monotonic() - t0 < 1.0  # shed instantly, no queueing
-        release.set()
-        stalled.join(10.0)
-        for t in waiters:
-            t.join(10.0)
-        b.close()
-
     def test_injected_queue_full_fault(self):
         faults = FaultInjector(parse_fault_spec("batcher.submit:queue_full:1.0"))
-        b = MicroBatcher(lambda items: [0] * len(items), fault_injector=faults)
+        b = MicroBatcher(_zeros_exec, fault_injector=faults)
         with pytest.raises(QueueFullError, match="injected"):
-            b.submit([1])
+            b.submit(_one_row())
         assert faults.fired() == {"batcher.submit:queue_full": 1}
 
     def test_injected_delay_stalls_submit(self):
@@ -262,8 +202,8 @@ class TestQueueBound:
         faults = FaultInjector(
             parse_fault_spec("batcher.submit:delay_ms:250"), sleep=slept.append
         )
-        b = MicroBatcher(lambda items: [0] * len(items), fault_injector=faults)
-        assert b.submit([1]) == [0]
+        b = MicroBatcher(_zeros_exec, fault_injector=faults)
+        assert b.submit(_one_row()).tolist() == [0]
         assert slept == [0.25]
 
 
@@ -311,9 +251,9 @@ class TestBrownoutHysteresis:
         store, _ = test_store
         c = _controller(store, brownout_target_ms=1.0, ewma_alpha=1.0)
         _brownout(c)
-        b = MicroBatcher(lambda items: [0] * len(items), overload=c)
+        b = MicroBatcher(_zeros_exec, overload=c)
         with pytest.raises(BrownoutError):
-            b.submit([1])
+            b.submit(_one_row())
 
     def test_validation(self, test_store):
         store, _ = test_store
@@ -889,18 +829,18 @@ class TestFullStackOverload:
             runner.stop()
 
 
-# -- DISPATCH_LOOP both-arms parity -------------------------------------------
+# -- windowed / direct mode parity -------------------------------------------
 
 
 class TestDispatchLoopOverloadParity:
-    """The dispatch loop (backends/dispatch.py) and the leader-collects
-    batcher are interchangeable arms of the same admission contract:
-    expired work is dropped at (ring) take time before packing, the shared
-    batcher.submit chaos site sheds identically, and every shed posture
-    answers the same wire response under DISPATCH_LOOP on/off."""
+    """The dispatch loop (windowed mode, backends/dispatch.py) and direct
+    mode (backends/batcher.py) share one admission contract: expired work
+    never reaches the device, the shared batcher.submit chaos site sheds
+    identically, and every shed posture answers the same wire response in
+    both modes. `arm` True is the dispatch loop, False direct mode."""
 
     @staticmethod
-    def _real_cache(store, dispatch_loop, **kw):
+    def _real_cache(store, arm, **kw):
         from api_ratelimit_tpu.backends.tpu import TpuRateLimitCache
         from api_ratelimit_tpu.limiter.base_limiter import BaseRateLimiter
 
@@ -908,12 +848,11 @@ class TestDispatchLoopOverloadParity:
         return TpuRateLimitCache(
             base,
             n_slots=1 << 12,
-            batch_window_seconds=0.002,
+            batch_window_seconds=0.002 if arm else 0.0,
             buckets=(8, 128),
             max_batch=128,
             use_pallas=False,
             stats_scope=store.scope("ratelimit"),
-            dispatch_loop=dispatch_loop,
             **kw,
         )
 

@@ -174,15 +174,27 @@ class TestReloadUnderFire:
 
     def test_pipelined_batcher_concurrent_counts_exact(self, test_store):
         """Same exactness through the DOUBLE-BUFFERED tpu backend: the
-        dispatcher launches batch k+1 while the collector drains batch k's
-        readback (backends/batcher.py), and no result may be lost,
+        dispatch loop launches batch k+1 while it redeems batch k's
+        readback (backends/dispatch.py), and no result may be lost,
         duplicated, or misrouted across that handoff."""
+        self._tpu_counts_exact(test_store, batch_window_seconds=0.0005)
+
+    def test_direct_mode_tpu_concurrent_counts_exact(self, test_store):
+        """The same through direct mode (TPU_BATCH_WINDOW=0): callers
+        take turns at the direct lock, one launch each."""
+        self._tpu_counts_exact(test_store, batch_window_seconds=0.0)
+
+    @staticmethod
+    def _tpu_counts_exact(test_store, batch_window_seconds):
         from api_ratelimit_tpu.backends.tpu import TpuRateLimitCache
 
         store, _ = test_store
         base = BaseRateLimiter(time_source=FakeTimeSource(5000), jitter_rand=None)
         cache = TpuRateLimitCache(
-            base, n_slots=1 << 12, batch_window_seconds=0.0005, max_batch=256
+            base,
+            n_slots=1 << 12,
+            batch_window_seconds=batch_window_seconds,
+            max_batch=256,
         )
         scope = store.scope("t")
         limit = RateLimit(

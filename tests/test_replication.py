@@ -517,7 +517,7 @@ class TestClientFailover:
 
 class TestRollbackArm:
     """REPL_ROLE unset / single-address == the pre-replication protocol,
-    byte for byte (the same discipline as HOST_FAST_PATH/DISPATCH_LOOP)."""
+    byte for byte."""
 
     def _capture_frame(self, tmp_path, address_arg):
         """Boot a client against a capturing server; returns the raw

@@ -89,27 +89,32 @@ class TestSettings:
             {
                 "TPU_PRECOMPILE": "false",
                 "TPU_BUCKETS": "16,256,4096",
-                "HOST_FAST_PATH": "false",
             }
         )
         assert s.tpu_precompile is False
         assert s.tpu_buckets == "16,256,4096"
         assert s.buckets() == (16, 256, 4096)
-        assert s.host_fast_path is False
 
     def test_hotpath_defaults(self):
         s = Settings()
         assert s.tpu_precompile is True
-        assert s.host_fast_path is True
-        assert s.dispatch_loop is True  # device-owner loop is the default
+        assert s.tpu_batch_window == 0.0  # direct mode is the default
         assert s.buckets() is None  # engine default ladder
 
-    def test_dispatch_loop_knob(self):
-        # the rollback arm (leader-collects batcher), HOST_FAST_PATH style
-        assert new_settings({"DISPATCH_LOOP": "false"}).dispatch_loop is False
-        assert new_settings({"DISPATCH_LOOP": "on"}).dispatch_loop is True
-        with pytest.raises(ValueError, match="DISPATCH_LOOP"):
-            new_settings({"DISPATCH_LOOP": "sideways"})
+    def test_removed_path_knobs_are_not_read(self):
+        """DISPATCH_LOOP and HOST_FAST_PATH are gone: a deployment that
+        still sets them boots on the one path of its mode, whatever the
+        value."""
+        s = new_settings(
+            {
+                "DISPATCH_LOOP": "sideways",
+                "HOST_FAST_PATH": "false",
+                "TPU_BATCH_WINDOW": "200us",
+            }
+        )
+        assert not hasattr(s, "dispatch_loop")
+        assert not hasattr(s, "host_fast_path")
+        assert s.tpu_batch_window == pytest.approx(0.0002)
 
     def test_journey_knobs(self):
         s = new_settings(

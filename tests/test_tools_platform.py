@@ -132,13 +132,6 @@ class TestHotpathProfile:
         # at least one profiled row mentions the service hot path
         assert any("should_rate_limit" in ln for ln in lines)
 
-    def test_legacy_arm_runs(self):
-        proc = _run_tool(
-            "tools.hotpath_profile", ("-n", "60", "--top", "4", "--legacy")
-        )
-        assert proc.returncode == 0, proc.stderr[-500:]
-        assert "path=legacy" in proc.stdout
-
     def test_slab_split_baseline(self):
         proc = _run_tool("tools.hotpath_profile", ("--slab-split",))
         assert proc.returncode == 0, proc.stderr[-500:]

@@ -6,6 +6,7 @@ import random
 import threading
 import time
 
+import numpy as np
 import pytest
 
 from api_ratelimit_tpu.backends import MemoryRateLimitCache
@@ -33,6 +34,13 @@ def req(*pairs, hits=1, domain="domain"):
         descriptors=tuple(Descriptor.of(p) for p in pairs),
         hits_addend=hits,
     )
+
+
+def _hits_block(hits):
+    """uint32[6, n] row block whose hits row carries `hits`."""
+    block = np.zeros((6, len(hits)), dtype=np.uint32)
+    block[2] = hits
+    return block
 
 
 def make_tpu_cache(ts, local_cache_size=0, window=0.0, n_slots=1 << 12):
@@ -243,230 +251,98 @@ class TestExactSlabOps:
 
 
 class TestMicroBatcher:
-    def test_direct_mode(self):
-        calls = []
-        b = MicroBatcher(lambda items: (calls.append(len(items)), items)[1])
-        assert b.submit([1, 2, 3]) == [1, 2, 3]
-        assert calls == [3]
-
-    def test_windowed_coalescing_and_order(self):
-        batches = []
-
-        def execute(items):
-            batches.append(list(items))
-            return [x * 10 for x in items]
-
-        b = MicroBatcher(execute, window_seconds=0.05, max_batch=100)
-        out = []
-        threads = [
-            threading.Thread(target=lambda i=i: out.append((i, b.submit([i]))))
-            for i in range(5)
-        ]
-        for t in threads:
-            t.start()
-        for t in threads:
-            t.join()
-        b.close()
-        assert sorted(x for _, [x] in out) == [0, 10, 20, 30, 40]
-        # coalesced into fewer launches than submissions
-        assert len(batches) < 5
-
-    def test_oversized_request_taken_alone(self):
-        sizes = []
-
-        def execute(items):
-            sizes.append(len(items))
-            return items
-
-        b = MicroBatcher(execute, window_seconds=0.01, max_batch=4)
-        res = b.submit(list(range(10)))
-        assert res == list(range(10))
-        assert sizes == [10]
-        b.close()
-
-    def test_warm_pipeline_skips_linger(self):
-        # items enqueued while a batch is executing launch immediately after
-        # it, without waiting the window again
-        import time as _time
-
-        executing = threading.Event()
-        release = threading.Event()
-
-        def execute(items):
-            executing.set()
-            release.wait(2.0)
-            release.clear()
-            return items
-
-        b = MicroBatcher(execute, window_seconds=0.5, max_batch=100)
-        t1 = threading.Thread(target=lambda: b.submit([1]))
-        t1.start()
-        assert executing.wait(2.0)  # batch 1 on device
-        executing.clear()
-
-        got = []
-        t2 = threading.Thread(target=lambda: got.append(b.submit([2])))
-        t2.start()
-        # wait until item 2 is actually enqueued (mid-execute) — a fixed
-        # sleep would flake under scheduler delay
-        deadline = _time.monotonic() + 2.0
-        while _time.monotonic() < deadline:
-            with b._lock:
-                if b._futures:
-                    break
-            _time.sleep(0.005)
-        s = _time.monotonic()
-        release.set()  # batch 1 finishes now
-        assert executing.wait(2.0)  # batch 2 launched...
-        launched_after = _time.monotonic() - s
-        release.set()
-        t1.join(2.0)
-        t2.join(2.0)
-        b.close()
-        assert got == [[2]]
-        # ...well inside the 0.5s window it would otherwise linger
-        assert launched_after < 0.25, f"lingered {launched_after:.3f}s"
-
-    def test_error_propagates_to_callers(self):
-        def execute(items):
-            raise RuntimeError("device on fire")
-
-        b = MicroBatcher(execute, window_seconds=0.01, max_batch=4)
-        with pytest.raises(RuntimeError, match="device on fire"):
-            b.submit([1])
-        b.close()
-
-
-class TestMicroBatcherPipelined:
-    """The double-buffered launch/collect mode (execute_launch +
-    execute_collect): launches overlap the previous batch's readback."""
+    """Direct mode (TPU_BATCH_WINDOW=0): the caller executes its own row
+    block under the direct lock."""
 
     @staticmethod
-    def _make(launch_log, collect_log, collect_gate=None, max_inflight=2):
-        def launch(items):
-            launch_log.append(list(items))
-            return list(items)
+    def _hits_echo(calls=None):
+        def execute(blocks):
+            if calls is not None:
+                calls.append([b.shape[1] for b in blocks])
+            return np.concatenate([b[2] for b in blocks])
 
-        def collect(token):
-            if collect_gate is not None:
-                collect_gate.wait(2.0)
-            collect_log.append(list(token))
-            return [x * 10 for x in token]
+        return execute
 
-        return MicroBatcher(
-            lambda items: [x * 10 for x in items],
-            window_seconds=0.01,
-            max_batch=4,
-            execute_launch=launch,
-            execute_collect=collect,
-            max_inflight=max_inflight,
-        )
+    def test_direct_mode(self):
+        calls = []
+        b = MicroBatcher(self._hits_echo(calls))
+        assert b.submit(_hits_block([1, 2, 3])).tolist() == [1, 2, 3]
+        assert calls == [[3]]
 
-    def test_results_and_order(self):
-        launches, collects = [], []
-        b = self._make(launches, collects)
-        out = []
-        threads = [
-            threading.Thread(target=lambda i=i: out.append(b.submit([i])))
-            for i in range(8)
-        ]
+    def test_error_propagates_to_callers(self):
+        def execute(blocks):
+            raise RuntimeError("device on fire")
+
+        b = MicroBatcher(execute)
+        with pytest.raises(RuntimeError, match="device on fire"):
+            b.submit(_hits_block([1]))
+        # the direct lock is released: the next submit runs again
+        with pytest.raises(RuntimeError, match="device on fire"):
+            b.submit(_hits_block([2]))
+        b.close()
+
+    def test_empty_block_never_executes(self):
+        calls = []
+        b = MicroBatcher(self._hits_echo(calls))
+        out = b.submit(np.zeros((6, 0), dtype=np.uint32))
+        assert out.dtype == np.uint32 and out.shape == (0,)
+        assert calls == []
+
+    def test_launches_are_single_flight(self):
+        """Concurrent callers never overlap inside the executor, and the
+        inflight gauge reads the launch in progress."""
+        active = []
+        peak = []
+        lock = threading.Lock()
+
+        def execute(blocks):
+            with lock:
+                active.append(1)
+                peak.append(len(active))
+            time.sleep(0.002)
+            with lock:
+                active.pop()
+            return np.concatenate([blk[2] for blk in blocks])
+
+        b = MicroBatcher(execute)
+        outs = {}
+
+        def worker(i):
+            outs[i] = b.submit(_hits_block([i, i + 1])).tolist()
+
+        threads = [threading.Thread(target=worker, args=(i,)) for i in range(8)]
         for t in threads:
             t.start()
-        for t in threads:
-            t.join()
-        b.close()
-        assert sorted(x for [x] in out) == [i * 10 for i in range(8)]
-        # every launch collected exactly once; collect ORDER is caller-
-        # driven (leader-collects), launch order is what sequences state
-        assert sorted(launches) == sorted(collects)
-
-    def test_launch_overlaps_collect(self):
-        # while batch 1's collect is gated, batch 2's LAUNCH must happen —
-        # that overlap is the whole point of the mode
-        launches, collects = [], []
-        gate = threading.Event()
-        b = self._make(launches, collects, collect_gate=gate)
-        t1 = threading.Thread(target=lambda: b.submit([1]))
-        t1.start()
-        deadline = time.monotonic() + 2.0
-        while not launches and time.monotonic() < deadline:
-            time.sleep(0.005)
-        t2 = threading.Thread(target=lambda: b.submit([2]))
-        t2.start()
-        deadline = time.monotonic() + 2.0
-        while len(launches) < 2 and time.monotonic() < deadline:
-            time.sleep(0.005)
-        assert len(launches) == 2, "launch 2 did not overlap collect 1"
-        assert collects == []  # nothing collected yet: both in flight
-        gate.set()
-        t1.join(2.0)
-        t2.join(2.0)
-        b.close()
-        assert sorted(collects) == [[1], [2]]  # order is caller-driven
-
-    def test_close_with_collects_in_flight(self):
-        # regression: close() while the bounded collect queue is full must
-        # not deadlock (the _CLOSE put happens outside the dispatch lock)
-        launches, collects = [], []
-        gate = threading.Event()
-        b = self._make(launches, collects, collect_gate=gate, max_inflight=1)
-        results = []
-        threads = [
-            threading.Thread(target=lambda i=i: results.append(b.submit([i])))
-            for i in range(3)
-        ]
-        for t in threads:
-            t.start()
-        deadline = time.monotonic() + 2.0
-        while not launches and time.monotonic() < deadline:
-            time.sleep(0.005)
-        closer = threading.Thread(target=b.close)
-        closer.start()
-        gate.set()
-        closer.join(5.0)
-        assert not closer.is_alive(), "close() deadlocked"
         for t in threads:
             t.join(5.0)
-        assert sorted(x for [x] in results) == [0, 10, 20]
+        assert max(peak) == 1
+        assert outs == {i: [i, i + 1] for i in range(8)}
+        assert b.inflight == 0 and b.queue_depth == 0
 
-    def test_collect_error_propagates(self):
-        def launch(items):
-            return list(items)
+    def test_flush_waits_for_the_launch_in_progress(self):
+        entered = threading.Event()
+        release = threading.Event()
 
-        def collect(token):
-            raise RuntimeError("readback failed")
+        def execute(blocks):
+            entered.set()
+            assert release.wait(5.0)
+            return np.concatenate([blk[2] for blk in blocks])
 
-        b = MicroBatcher(
-            lambda items: items,
-            window_seconds=0.01,
-            max_batch=4,
-            execute_launch=launch,
-            execute_collect=collect,
-        )
-        with pytest.raises(RuntimeError, match="readback failed"):
-            b.submit([1])
-        b.close()
-
-    def test_flush_waits_for_collects(self):
-        launches, collects = [], []
-        gate = threading.Event()
-        b = self._make(launches, collects, collect_gate=gate)
-        t = threading.Thread(target=lambda: b.submit([7]))
+        b = MicroBatcher(execute)
+        t = threading.Thread(target=lambda: b.submit(_hits_block([7])))
         t.start()
-        deadline = time.monotonic() + 2.0
-        while not launches and time.monotonic() < deadline:
-            time.sleep(0.005)
+        assert entered.wait(5.0)
+        assert b.inflight == 1
         flushed = threading.Event()
         f = threading.Thread(target=lambda: (b.flush(), flushed.set()))
         f.start()
         time.sleep(0.05)
-        assert not flushed.is_set()  # collect still gated => not idle
-        gate.set()
-        f.join(2.0)
+        assert not flushed.is_set()  # launch still running
+        release.set()
+        f.join(5.0)
         assert flushed.is_set()
-        t.join(2.0)
+        t.join(5.0)
         b.close()
-
 
 class TestBlockNativePath:
     """The sidecar server's block-native path (engine block_mode=True):

@@ -7,7 +7,6 @@ cProfile and printed as a top-N cumulative table:
 
     python -m tools.hotpath_profile                 # 2000 requests, top 25
     python -m tools.hotpath_profile -n 500 --top 10 --sort tottime
-    python -m tools.hotpath_profile --legacy        # pin the pre-vectorization path
     make profile
 
 The dispatch loop's owner thread is not profiled here: its
@@ -46,11 +45,6 @@ def main(argv=None) -> int:
         "--sort",
         default="cumulative",
         choices=["cumulative", "tottime", "ncalls"],
-    )
-    parser.add_argument(
-        "--legacy",
-        action="store_true",
-        help="pin the legacy per-object host path (the A/B arm)",
     )
     parser.add_argument(
         "--frontend",
@@ -100,7 +94,6 @@ def main(argv=None) -> int:
         "flat_per_second",
         bench._FLAT,
         telemetry=True,
-        host_fast_path=not args.legacy,
     )
     reqs = bench._requests_for("flat_per_second", 2048)
     # warmup: compile/prime outside the profiled region
@@ -119,10 +112,7 @@ def main(argv=None) -> int:
             service.should_rate_limit(reqs[i % len(reqs)])
         prof.disable()
         elapsed = time.perf_counter() - t0
-        print(
-            f"[hotpath] rate={round(args.n / elapsed)}/s requests={args.n} "
-            f"path={'legacy' if args.legacy else 'fast'}"
-        )
+        print(f"[hotpath] rate={round(args.n / elapsed)}/s requests={args.n}")
         out = io.StringIO()
         stats = pstats.Stats(prof, stream=out)
         stats.sort_stats(args.sort).print_stats(args.top)
