@@ -41,13 +41,14 @@ is unique by construction: candidates are distinct fingerprints, so the
 (weight, fp_hi, fp_lo) triple never ties — the winner scatter keeps the
 slab's unique_indices discipline.
 
-The per-item scan arithmetic (match way, victim argmin) has the exact
-_way_scan_kernel shape and runs as a Mosaic kernel on the ways == 128
-geometry (the Pallas arm); the set gathers and the phase A/B scatters
-stay XLA in both arms — the same division of labor as the slab step
-(native dynamic gather/scatter beats kernel emulation;
-ops/pallas_slab.py module docstring). Counts stay below 2^31 by the
-drain-halving cadence, so the kernels' int32 views order identically to
+On the default TPU geometry (128 lanes, ways == 128) the sketch is ONE
+set, and the Pallas arm runs phase A as one Mosaic kernel
+(pallas_sketch_match) that reads the set's 128 entries as scalars and
+streams the candidates lane-dense: no per-candidate copy of the set, no
+scatter. With several sets, and off the Pallas arm, each candidate's set
+is gathered and scanned in XLA (_sketch_scan) and phase A scatter-adds;
+phase B stays XLA everywhere. Counts stay below 2^31 by the
+drain-halving cadence, so the int32 views order identically to
 uint32 — the same contract the slab kernels document.
 
 Everything here is deterministic and bit-exactly mirrored by the numpy
@@ -58,11 +59,12 @@ XLA twin, the Pallas interpret arm, and the oracle to one state.
 from __future__ import annotations
 
 import functools
-import math
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 SKETCH_PLANES = 3
 PLANE_FP_LO, PLANE_FP_HI, PLANE_COUNT = range(3)
@@ -97,7 +99,7 @@ def make_sketch(lanes: int, device=None) -> jnp.ndarray:
 
 
 def _sketch_scan(rows_lo, rows_hi, rows_cnt, q_lo, q_hi):
-    """The XLA twin of the Mosaic sketch scan: per candidate, over its
+    """The set scan of a sketch with several sets: per candidate, over its
     gathered set planes — (int32[b] match way, bool[b] match any,
     int32[b] victim way = argmin count with first-way tiebreak, uint32[b]
     victim count). int32 count view: the drain-halving cadence keeps
@@ -112,71 +114,86 @@ def _sketch_scan(rows_lo, rows_hi, rows_cnt, q_lo, q_hi):
     return match_way, match_any, vic_way, vic_cnt
 
 
-def _sketch_scan_kernel(q_lo_ref, q_hi_ref, lo_ref, hi_ref, cnt_ref, out_ref):
-    """Mosaic sketch scan — the _way_scan_kernel shape on the sketch
-    planes: a candidate's set per sublane row, match/argmin as single
-    cross-lane reductions. One output tile, results packed into lanes
-    0-3 (caller slices; a (b, 4) output would fight the lane tiling)."""
-    lanes = jax.lax.broadcasted_iota(jnp.int32, cnt_ref.shape, 1)
-    w = cnt_ref.shape[1]
-    cnt = cnt_ref[...]
-    occupied = cnt > 0
-    match = occupied & (lo_ref[...] == q_lo_ref[...]) & (hi_ref[...] == q_hi_ref[...])
+def _sketch_match_kernel(sk_ref, q_lo_ref, q_hi_ref, cw_ref, m_ref, add_ref):
+    """Mosaic phase A of a one-set sketch: the sketch's entries as SMEM
+    scalars, the candidates lane-dense. Per entry j, every item of the
+    block compares against it; an item's first matching entry (the lowest
+    way, _sketch_scan's argmax rule) sets its matched flag and adds its
+    candidate weight into lane j of the increment, which stays resident
+    across the sequential grid."""
+    ways = add_ref.shape[1]
 
-    m_any = jnp.max(match.astype(jnp.int32), axis=1, keepdims=True)
-    m_way = jnp.min(
-        jnp.where(match, lanes, jnp.int32(w)), axis=1, keepdims=True
-    )
-    # argmin via min + first-lane-at-min — ties resolve to the lowest way,
-    # matching jnp.argmin in the XLA twin
-    min_cnt = jnp.min(cnt, axis=1, keepdims=True)
-    v_way = jnp.min(
-        jnp.where(cnt == min_cnt, lanes, jnp.int32(w)), axis=1, keepdims=True
-    )
-    out_ref[...] = jnp.where(
-        lanes == 0,
-        jnp.where(m_any > 0, m_way, jnp.int32(0)),
-        jnp.where(
-            lanes == 1,
-            m_any,
-            jnp.where(lanes == 2, v_way, min_cnt),
-        ),
-    )
+    @pl.when(pl.program_id(0) == 0)
+    def _():
+        add_ref[...] = jnp.zeros_like(add_ref)
+
+    m_ref[...] = jnp.zeros_like(m_ref)
+    lane = jax.lax.broadcasted_iota(jnp.int32, add_ref.shape, 1)
+
+    def entry(j, c):
+        @pl.when(sk_ref[PLANE_COUNT * ways + j] > 0)  # occupied
+        def _():
+            eq = (q_lo_ref[...] == sk_ref[PLANE_FP_LO * ways + j]) & (
+                q_hi_ref[...] == sk_ref[PLANE_FP_HI * ways + j]
+            )
+            m = m_ref[...]
+            first = jnp.where(eq & (m == 0), cw_ref[...], jnp.int32(0))
+            m_ref[...] = m | eq.astype(jnp.int32)
+            s = jnp.sum(jnp.sum(first, axis=0, keepdims=True), axis=1, keepdims=True)
+            add_ref[...] += jnp.where(lane == j, s, jnp.int32(0))
+
+        return c
+
+    jax.lax.fori_loop(0, ways, entry, 0)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def pallas_sketch_scan(rows_lo, rows_hi, rows_cnt, q_lo, q_hi, interpret=False):
-    """Run the sketch set scan as a Mosaic kernel; bit-identical to
-    _sketch_scan (pinned by tests/test_hotkeys_fuzz.py in interpret
-    mode). Requires ways == 128: a set per sublane row is the shape."""
-    from jax.experimental import pallas as pl
+def pallas_sketch_match(sketch, q_lo, q_hi, cand_weight, interpret=False):
+    """Phase A of a one-set (n_sets == 1, ways == 128) sketch as one
+    Mosaic kernel, with no per-item copy of the set: (bool[b] matched,
+    uint32[ways] the count plane's increment). cand_weight is the
+    candidate's weight, 0 where the item is no candidate. Bit-identical to
+    _sketch_scan's match plus phase A's scatter-add (pinned by
+    tests/test_hotkeys_fuzz.py in interpret mode)."""
+    from .pallas_slab import BLOCK_ROWS, LANES, out_vma
 
-    from .pallas_slab import BLOCK_ROWS, LANES
+    _, ways = sketch.shape
+    if ways != LANES:
+        raise ValueError(f"pallas sketch match needs one {LANES}-way set, got {ways}")
+    (b,) = q_lo.shape
+    # rows of 128 items, padded to whole (8, 128) tiles, and past one
+    # block to whole blocks: Mosaic takes no other block shape
+    rows = -(-b // LANES)
+    tile = 8 if rows <= BLOCK_ROWS else BLOCK_ROWS
+    rows = -(-rows // tile) * tile
+    block_rows = min(rows, BLOCK_ROWS)
 
-    b, w = rows_lo.shape
-    if w != LANES:
-        raise ValueError(f"pallas sketch scan needs ways == {LANES}, got {w}")
-    block_rows = math.gcd(b, BLOCK_ROWS)
+    def as2d(x):  # padding items are no candidate: weight 0
+        x = jnp.pad(x.astype(jnp.int32), (0, rows * LANES - b))
+        return x.reshape(rows, LANES)
 
-    as_i32 = lambda x: x.astype(jnp.int32)
-    q_lo_b = jnp.broadcast_to(as_i32(q_lo)[:, None], (b, w))
-    q_hi_b = jnp.broadcast_to(as_i32(q_hi)[:, None], (b, w))
-
-    block = pl.BlockSpec((block_rows, LANES), lambda i: (i, 0))
-    (out,) = pl.pallas_call(
-        _sketch_scan_kernel,
-        grid=(b // block_rows,),
-        in_specs=[block] * 5,
-        out_specs=[block],
-        out_shape=[jax.ShapeDtypeStruct((b, w), jnp.int32)],
-        interpret=interpret,
-    )(q_lo_b, q_hi_b, as_i32(rows_lo), as_i32(rows_hi), as_i32(rows_cnt))
-    return (
-        out[:, 0],
-        out[:, 1] > 0,
-        out[:, 2],
-        out[:, 3].astype(jnp.uint32),
+    items = [as2d(q_lo), as2d(q_hi), as2d(cand_weight)]
+    sk = sketch.astype(jnp.int32).reshape(-1)
+    block = pl.BlockSpec((block_rows, LANES), lambda i, *_: (i, 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(rows // block_rows,),
+        in_specs=[block] * len(items),
+        out_specs=[block, pl.BlockSpec((1, LANES), lambda i, *_: (0, 0))],
     )
+    vma = out_vma(sk, *items)
+    m, add = pl.pallas_call(
+        _sketch_match_kernel,
+        grid_spec=grid_spec,
+        out_shape=[
+            jax.ShapeDtypeStruct((rows, LANES), jnp.int32, vma=vma),
+            jax.ShapeDtypeStruct((1, LANES), jnp.int32, vma=vma),
+        ],
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
+        name="sketch_match",
+        interpret=interpret,
+    )(sk, *items)
+    return m.reshape(-1)[:b] > 0, add[0].astype(jnp.uint32)
 
 
 def sketch_update(
@@ -190,27 +207,17 @@ def sketch_update(
     interpret: bool = False,
 ) -> jnp.ndarray:
     """One launch's sketch update (module docstring). Traced inside the
-    slab step's jit, so the gather/scan/scatter chain fuses with the
-    launch program — the sketch never costs an extra device round trip."""
+    slab step's jit, so the update fuses with the launch program — the
+    sketch never costs an extra device round trip.
+
+    On the Pallas arm a one-set sketch (the default TPU geometry) takes
+    phase A in pallas_sketch_match, which reads the set where it lies;
+    every other shape gathers each candidate's set and scans it."""
     lanes = sketch.shape[1]
     n_sets = lanes // ways
     u0 = jnp.uint32(0)
     set_idx = (fp_lo & jnp.uint32(n_sets - 1)).astype(jnp.int32)
     sets = sketch.reshape(SKETCH_PLANES, n_sets, ways)
-    rows_lo = sets[PLANE_FP_LO][set_idx]
-    rows_hi = sets[PLANE_FP_HI][set_idx]
-    rows_cnt = sets[PLANE_COUNT][set_idx]
-
-    if use_pallas and ways == 128:
-        m_way, m_any, v_way, v_cnt = pallas_sketch_scan(
-            rows_lo, rows_hi, rows_cnt, fp_lo, fp_hi, interpret=interpret
-        )
-    else:
-        m_way, m_any, v_way, v_cnt = _sketch_scan(
-            rows_lo, rows_hi, rows_cnt, fp_lo, fp_hi
-        )
-
-    drop = jnp.int32(lanes)  # out-of-bounds scatter sentinel (mode="drop")
 
     # --- phase A: matched candidates accumulate in place. The lanes are
     # unique by construction: a fingerprint occupies at most one lane of
@@ -218,11 +225,21 @@ def sketch_update(
     # pure function of fp_lo), and candidates are distinct keys — so two
     # candidates can never match the same lane. unique_indices lets XLA
     # compile the add as gather+select instead of a serialized scatter. ---
-    matched = m_any & cand
-    add_lane = jnp.where(matched, set_idx * jnp.int32(ways) + m_way, drop)
-    cnt_plane = sketch[PLANE_COUNT].at[add_lane].add(
-        jnp.where(matched, weight, u0), mode="drop", unique_indices=True
-    )
+    if use_pallas and ways == 128 and n_sets == 1:
+        m_any, add = pallas_sketch_match(
+            sketch, fp_lo, fp_hi, jnp.where(cand, weight, u0),
+            interpret=interpret,
+        )
+        cnt_plane = sketch[PLANE_COUNT] + add
+    else:
+        rows = [sets[p][set_idx] for p in range(SKETCH_PLANES)]
+        m_way, m_any, _, _ = _sketch_scan(*rows, fp_lo, fp_hi)
+        matched = m_any & cand
+        drop = jnp.int32(lanes)  # out-of-bounds scatter sentinel (mode="drop")
+        add_lane = jnp.where(matched, set_idx * jnp.int32(ways) + m_way, drop)
+        cnt_plane = sketch[PLANE_COUNT].at[add_lane].add(
+            jnp.where(matched, weight, u0), mode="drop", unique_indices=True
+        )
 
     # --- phase B: one winner per set among unmatched candidates, ranked
     # lexicographically by (weight, fp_hi, fp_lo) via three masked

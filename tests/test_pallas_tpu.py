@@ -44,6 +44,12 @@ from test_pallas_slab import (  # noqa: E402
     run_lean_decide_matches_full,
     run_update_matches_xla_over_stream,
 )
+from test_hotkeys_fuzz import (  # noqa: E402
+    ONE_SET_CASES,
+    STREAMS,
+    run_one_set_case,
+    run_one_set_stream,
+)
 
 
 def test_update_matches_xla_on_chip():
@@ -104,3 +110,24 @@ def test_packbits_muladd_on_chip():
         mask = rng.rand(size) < 0.41
         got = np.asarray(jax.jit(packbits_muladd)(jnp.asarray(mask)))
         np.testing.assert_array_equal(got, np.packbits(mask))
+
+
+@pytest.mark.parametrize("case", ONE_SET_CASES)
+def test_sketch_match_edge_case_on_chip(case):
+    """The one-set sketch kernel compiled through Mosaic: phase A against
+    the gather-and-scan reference, the whole update against the XLA twin
+    and the oracle (tests/test_hotkeys_fuzz.py's interpret-mode cases)."""
+    run_one_set_case(case, interpret=False)
+
+
+@pytest.mark.parametrize("width", [128, 384, 1024, 1536, 65536])
+def test_sketch_match_streams_on_chip(width):
+    """The zipf, uniform and churn streams through the compiled one-set
+    kernel at the edge's launch width (128), the owner's (65,536), and
+    widths whose rows of 128 the kernel pads to whole tiles."""
+    for stream in sorted(STREAMS):
+        for warm in (False, True):
+            run_one_set_stream(
+                stream, warm, width, launches=12 if width < 65536 else 4,
+                interpret=False,
+            )

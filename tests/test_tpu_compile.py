@@ -65,7 +65,7 @@ def _check(compiled, pallas: bool) -> None:
     assert 0 < total < HBM_BYTES, total
 
 
-@pytest.mark.parametrize("bucket", [1024, 8192])
+@pytest.mark.parametrize("bucket", [128, 1024, 8192])
 def test_step_after_pallas_with_sketch(one_chip, bucket):
     """The served launch: after-mode step, Mosaic kernels, hot-key sketch."""
     import jax.numpy as jnp
@@ -167,3 +167,75 @@ def test_health_fold(one_chip):
     vector = _u32((HEALTH_WIDTH,), one_chip)
     compiled = _fold_health.lower(n, *[vector] * HEALTH_FOLD).compile()
     _check(compiled, pallas=False)
+
+
+def _owner_step(one_chip, slots, bucket, lanes):
+    """The served after-mode step with a sketch of `lanes` lanes."""
+    import jax.numpy as jnp
+
+    from api_ratelimit_tpu.ops.sketch import SKETCH_PLANES, sketch_ways
+    from api_ratelimit_tpu.ops.slab import ROW_WIDTH, SlabState, slab_step_after
+
+    compiled = slab_step_after.lower(
+        SlabState(table=_u32((slots, ROW_WIDTH), one_chip)),
+        _u32((7, bucket), one_chip),
+        ways=WAYS,
+        out_dtype=jnp.uint8,
+        use_pallas=True,
+        multi_algo=False,
+        sketch=_u32((SKETCH_PLANES, lanes), one_chip),
+        sketch_ways=sketch_ways(WAYS, lanes),
+    ).compile()
+    _check(compiled, pallas=True)
+    return compiled.as_text()
+
+
+def _defs(text):
+    """HLO value name -> its output type, from the compiled text."""
+    import re
+
+    return dict(re.findall(r"^\s*(?:ROOT )?(%[\w.-]+) = (\S+|\([^)]*\)) ", text, re.M))
+
+
+def test_owner_sketch_is_one_kernel(one_chip):
+    """incrby_owner's launch (2^24 slots, 65,536 lanes) on the default
+    geometry: the one-set sketch runs as the sketch_match kernel, fed no
+    per-item (65536, 128) copy of the set, and no scatter writes the
+    u32[128] count plane."""
+    import re
+
+    text = _owner_step(one_chip, 1 << 24, 65536, LANES)
+    calls = re.findall(r"^\s*(?:ROOT )?%sketch_match[\w.-]* = .*? custom-call\(([^)]*)\)", text, re.M)
+    assert len(calls) == 1, calls
+    defs = _defs(text)
+    operands = re.findall(r"%[\w.-]+", calls[0])
+    assert operands and all(o in defs for o in operands), (operands, calls)
+    assert not [o for o in operands if "[65536,128]" in defs[o]], calls
+    assert "pallas_sketch_scan" not in text
+    assert not re.search(r"= u32\[128\]\S* scatter\(", text)
+
+
+def test_several_sketch_sets_keep_the_set_scan(one_chip):
+    """HOTKEY_LANES=256 gives two 128-way sets: each candidate's set is
+    gathered and scanned in XLA and phase A scatter-adds into the u32[256]
+    count plane; the one-set kernel is not in the program."""
+    import re
+
+    text = _owner_step(one_chip, SLOTS, 8192, 2 * LANES)
+    assert re.search(r"= u32\[256\]\S* scatter\(", text)
+    assert "sketch_match" not in text
+    assert "pallas_sketch_scan" not in text
+
+
+
+@pytest.mark.parametrize("items", [384, 1536])
+def test_sketch_kernel_compiles_at_uneven_widths(one_chip, items):
+    """A launch width whose rows of 128 items are neither a power of two
+    nor a multiple of 8 (384: 3 rows; 1,536: 12) compiles the one-set
+    sketch kernel: it pads its rows to whole (8, 128) tiles."""
+    from api_ratelimit_tpu.ops.sketch import SKETCH_PLANES, pallas_sketch_match
+
+    compiled = pallas_sketch_match.lower(
+        _u32((SKETCH_PLANES, LANES), one_chip), *[_u32((items,), one_chip)] * 3
+    ).compile()
+    _check(compiled, pallas=True)
