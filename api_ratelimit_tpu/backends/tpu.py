@@ -186,10 +186,11 @@ class SlabDeviceEngine:
         the runner's `ratelimit` scope). When set, the engine records the
         per-stage device histograms — <scope>.device.{pack_ms,launch_ms,
         readback_ms}, <scope>.slab.{lock_wait_ms,health_drain_ms,
-        health_vectors} — and hands <scope>.batcher to the direct-mode
-        batcher and <scope>.dispatch to the dispatch loop for their
-        queue-wait/batch-size/depth telemetry. None (the default) keeps
-        the hot path entirely free of stats work.
+        health_vectors}, and on a mesh <scope>.shard.{topk_ms,route_ms,
+        collect_ms,routed_rows,padded_lanes} — and hands <scope>.batcher
+        to the direct-mode batcher and <scope>.dispatch to the dispatch
+        loop for their queue-wait/batch-size/depth telemetry. None (the
+        default) keeps the hot path entirely free of stats work.
 
         precompile: compile the whole bucket ladder (every launch shape x
         readback dtype) at construction so no request ever rides a JIT
@@ -263,7 +264,8 @@ class SlabDeviceEngine:
             # mesh engines route per shard by default
             # (SHARD_ROUTED_BATCHING; the false arm is the byte-identical
             # global-bucket rollback) and take the hot-key tier + the
-            # host-side top-K fallback in place of the device sketch
+            # host-side top-K fallback in place of the device sketch; their
+            # host stages record under <scope>.shard
             self._engine = ShardedSlabEngine(
                 mesh=mesh,
                 n_slots_global=n_slots,
@@ -274,6 +276,7 @@ class SlabDeviceEngine:
                 hot_salt_ways=int(hot_tier_salt_ways),
                 hotkey_lanes=int(hotkey_lanes),
                 hotkey_k=int(hotkey_k),
+                scope=None if scope is None else scope.scope("shard"),
             )
             self._state = None
             self._ways = self._engine.ways

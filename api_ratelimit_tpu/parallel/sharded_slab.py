@@ -80,6 +80,7 @@ from ..ops.slab import (
     live_slot_count,
     validate_ways,
 )
+from ..tracing import host_span
 
 _log = logging.getLogger(__name__)
 
@@ -318,6 +319,18 @@ def _routed_body(table, block, *, ways: int, cap: int, use_pallas: bool):
     return state.table, after, health
 
 
+@functools.partial(
+    jax.jit, static_argnames=("ways", "cap", "use_pallas"), donate_argnums=(0,)
+)
+def slab_step_routed(table, block, *, ways: int, cap: int, use_pallas: bool):
+    """The routed arm's per-device launch: _routed_body jitted under a
+    stable name, so its module reads jit_slab_step_routed in a profile
+    (beside the single chip's jit_slab_step_after) and a trace reader
+    finds the routed step by name. One program per (bucket, cap,
+    use_pallas, device)."""
+    return _routed_body(table, block, ways=ways, cap=cap, use_pallas=use_pallas)
+
+
 def _pcts(samples) -> dict:
     """p50/p99 of a timing deque (ns); zeros when empty."""
     if not samples:
@@ -367,7 +380,17 @@ class ShardedSlabEngine:
     remaps slice counters so callers' `after > limit` compare still
     yields the decision. hotkey_lanes > 0 arms the host-side top-K
     fallback (ops/sketch.py HostTopK) that feeds the tier and the
-    ratelimit.hotkeys.* gauges on the mesh path."""
+    ratelimit.hotkeys.* gauges on the mesh path.
+
+    scope (optional stats Scope, the backend's `ratelimit.shard`) records
+    one sample per launch of each host stage the mesh adds — topk_ms (the
+    host top-K fold), route_ms (owner hash, bincount, argsort and the
+    per-shard block fill), collect_ms (per-shard readback and unscatter)
+    — and of routed_rows and padded_lanes (real rows, and lanes launched
+    with padding). Each stage also opens a ratelimit.shard.* span while a
+    profile is captured (tracing/host.py), as does the per-device launch
+    loop (ratelimit.shard.launch). None keeps the launch free of stats
+    work."""
 
     def __init__(
         self,
@@ -381,6 +404,7 @@ class ShardedSlabEngine:
         hotkey_lanes: int = 0,
         hotkey_k: int = 16,
         hot_min_count: int = 4096,
+        scope=None,
     ):
         if mesh is None:
             mesh = make_mesh()
@@ -417,7 +441,6 @@ class ShardedSlabEngine:
         self._algos_seen = False
         self._after_steps: dict[int, object] = {}
         self._compact_steps: dict[int, object] = {}
-        self._routed_steps: dict[int, object] = {}
         self._blocks_sharding = NamedSharding(mesh, P(axis, None, None))
         if self._routed:
             # per-shard batching: one committed table per device instead
@@ -469,6 +492,20 @@ class ShardedSlabEngine:
         self._t_bucket_ns: collections.deque = collections.deque(maxlen=4096)
         self._t_pad_ns: collections.deque = collections.deque(maxlen=4096)
         self._t_launch_ns: collections.deque = collections.deque(maxlen=4096)
+        self._h_topk = self._h_route = self._h_collect = None
+        self._h_rows = self._h_lanes = None
+        if scope is not None:
+            from ..stats.store import DEFAULT_SIZE_BUCKETS
+
+            self._h_topk = scope.histogram("topk_ms")
+            self._h_route = scope.histogram("route_ms")
+            self._h_collect = scope.histogram("collect_ms")
+            self._h_rows = scope.histogram(
+                "routed_rows", boundaries=DEFAULT_SIZE_BUCKETS
+            )
+            self._h_lanes = scope.histogram(
+                "padded_lanes", boundaries=DEFAULT_SIZE_BUCKETS
+            )
 
         # -- replicated hot-key tier (routed arm only) --
         hot_tier = bool(hot_tier)
@@ -535,7 +572,6 @@ class ShardedSlabEngine:
                 )
             self._after_steps.clear()
             self._compact_steps.clear()
-            self._routed_steps.clear()
 
     def _guard_algos(self, packed: np.ndarray) -> None:
         """Per-launch check for direct engine callers (the backend has
@@ -622,52 +658,68 @@ class ShardedSlabEngine:
         # feed the host top-K fallback BEFORE any hot-tier salting —
         # detection must see home fingerprints, not slice aliases
         if self._hostkeys is not None:
-            with self._hotkeys_lock:
-                self._hostkeys.update(
-                    packed[ROW_FP_LO, valid_idx],
-                    packed[ROW_FP_HI, valid_idx],
-                    packed[ROW_HITS, valid_idx],
-                )
+            with host_span("ratelimit.shard.topk"):
+                tk = time.perf_counter_ns()
+                with self._hotkeys_lock:
+                    self._hostkeys.update(
+                        packed[ROW_FP_LO, valid_idx],
+                        packed[ROW_FP_HI, valid_idx],
+                        packed[ROW_HITS, valid_idx],
+                    )
+                if self._h_topk is not None:
+                    self._h_topk.record((time.perf_counter_ns() - tk) / 1e6)
 
         hot_remap = None
         hot_epoch = 0
         if self._hot_tier:
             packed, hot_remap, hot_epoch = self._salt_hot(packed, valid_idx)
 
-        # MUST mirror _owner_mask's device-side formula ((fp_lo ^ fp_hi) mod
-        # n_dev) exactly — a mismatch silently routes keys to shards that
-        # don't own them and corrupts counters.
-        owner = (
-            (packed[ROW_FP_LO, valid_idx] ^ packed[ROW_FP_HI, valid_idx])
-            % np.uint32(n_dev)
-        ).astype(np.int64)
-        counts = np.bincount(owner, minlength=n_dev)
-        route = np.argsort(owner, kind="stable")
-        routed_idx = valid_idx[route]  # original positions, shard-grouped
-        routed_owner = owner[route]
-        starts = np.zeros(n_dev + 1, dtype=np.int64)
-        starts[1:] = np.cumsum(counts)
-        t1 = time.perf_counter_ns()
+        with host_span("ratelimit.shard.route"):
+            tr = time.perf_counter_ns()
+            # MUST mirror _owner_mask's device-side formula ((fp_lo ^
+            # fp_hi) mod n_dev) exactly — a mismatch silently routes keys
+            # to shards that don't own them and corrupts counters.
+            owner = (
+                (packed[ROW_FP_LO, valid_idx] ^ packed[ROW_FP_HI, valid_idx])
+                % np.uint32(n_dev)
+            ).astype(np.int64)
+            counts = np.bincount(owner, minlength=n_dev)
+            route = np.argsort(owner, kind="stable")
+            routed_idx = valid_idx[route]  # original positions, shard-grouped
+            routed_owner = owner[route]
+            starts = np.zeros(n_dev + 1, dtype=np.int64)
+            starts[1:] = np.cumsum(counts)
+            t1 = time.perf_counter_ns()
+            if self._routed:
+                blocks = self._routed_blocks(
+                    packed, min_bucket, counts, routed_idx, starts, hot_epoch
+                )
+            else:
+                # power-of-two bucket >= the fullest shard (>=128 for lane
+                # alignment)
+                bucket = 128
+                while bucket < max(int(min_bucket), counts.max()):
+                    bucket <<= 1
+                within = (
+                    np.arange(routed_idx.size, dtype=np.int64)
+                    - starts[routed_owner]
+                )
+                blocks = np.zeros((n_dev, 7, bucket), dtype=np.uint32)
+                blocks[routed_owner, :, within] = packed[:, routed_idx].T
+                # per-item columns carried garbage into the scalar row;
+                # restamp it
+                blocks[:, ROW_SCALARS, 0] = packed[ROW_SCALARS, 0]
+                blocks[:, ROW_SCALARS, 1] = packed[ROW_SCALARS, 1]
+                blocks[:, ROW_SCALARS, 2] = packed[ROW_SCALARS, 2]
+            t2 = time.perf_counter_ns()
+        if self._h_route is not None:
+            self._h_route.record((t2 - tr) / 1e6)
 
         if self._routed:
             return self._launch_routed(
-                packed, cap, min_bucket, b, counts, routed_idx, starts,
-                hot_remap, hot_epoch, t0, t1,
+                blocks, cap, b, counts, routed_idx, starts, hot_remap,
+                t0, t1, t2,
             )
-
-        # power-of-two bucket >= the fullest shard (>=128 for lane alignment)
-        bucket = 128
-        while bucket < max(int(min_bucket), counts.max()):
-            bucket <<= 1
-        within = np.arange(routed_idx.size, dtype=np.int64) - starts[routed_owner]
-
-        blocks = np.zeros((n_dev, 7, bucket), dtype=np.uint32)
-        blocks[routed_owner, :, within] = packed[:, routed_idx].T
-        # per-item columns carried garbage into the scalar row; restamp it
-        blocks[:, ROW_SCALARS, 0] = packed[ROW_SCALARS, 0]
-        blocks[:, ROW_SCALARS, 1] = packed[ROW_SCALARS, 1]
-        blocks[:, ROW_SCALARS, 2] = packed[ROW_SCALARS, 2]
-        t2 = time.perf_counter_ns()
 
         # one jit wrapper per cap; jax.jit itself retraces per bucket shape
         step = self._compact_steps.get(cap)
@@ -681,7 +733,8 @@ class ShardedSlabEngine:
             self._compact_steps[cap] = step
         blocks_dev = jax.device_put(blocks, self._blocks_sharding)
         with self._state_lock:
-            self._state, after_blocks, health = step(self._state, blocks_dev)
+            with host_span("ratelimit.shard.launch"):
+                self._state, after_blocks, health = step(self._state, blocks_dev)
             self._health.park(health)
             self._note_routing_locked(
                 counts, n_dev * bucket, t0, t1, t2, time.perf_counter_ns()
@@ -689,23 +742,18 @@ class ShardedSlabEngine:
         self._drain_health_if_full()
         return (after_blocks, routed_idx, routed_owner, within, b, hot_remap)
 
-    def _launch_routed(
-        self, packed, cap, min_bucket, b, counts, routed_idx, starts,
-        hot_remap, hot_epoch, t0, t1,
-    ):
-        """Routed-arm launch: one block per NON-EMPTY shard, each padded
-        only to its own power-of-two rung, dispatched as independent
-        per-device jitted calls. jax's async dispatch returns before any
-        program finishes, so the K launches overlap on device exactly
-        like the compact arm's single SPMD launch — minus the dead lanes.
+    def _routed_blocks(
+        self, packed, min_bucket, counts, routed_idx, starts, hot_epoch
+    ) -> dict:
+        """Routed-arm block fill: one block per NON-EMPTY shard, each
+        padded only to its own power-of-two rung.
 
         min_bucket keeps its compile-pinning meaning per shard, but the
         FLOOR stays 128 even when callers pass more: the whole point of
         this arm is that a cold shard must not inherit a hot shard's
         rung."""
-        n_dev = len(self._devices)
         blocks: dict[int, np.ndarray] = {}
-        for d in range(n_dev):
+        for d in range(len(self._devices)):
             c = int(counts[d])
             if not c:
                 continue
@@ -723,27 +771,27 @@ class ShardedSlabEngine:
             # hot-set version routed this batch
             blk[ROW_SCALARS, 3] = np.uint32(hot_epoch)
             blocks[d] = blk
-        t2 = time.perf_counter_ns()
+        return blocks
 
-        step = self._routed_steps.get(cap)
-        if step is None:
-            step = jax.jit(
-                functools.partial(
-                    _routed_body,
-                    ways=self.ways,
-                    cap=cap,
-                    use_pallas=self._use_pallas,
-                ),
-                donate_argnums=(0,),
-            )
-            self._routed_steps[cap] = step
+    def _launch_routed(
+        self, blocks, cap, b, counts, routed_idx, starts, hot_remap, t0, t1, t2
+    ):
+        """Routed-arm launch: the shards' blocks dispatched as independent
+        per-device jitted calls (slab_step_routed). jax's async dispatch
+        returns before any program finishes, so the K launches overlap on
+        device exactly like the compact arm's single SPMD launch — minus
+        the dead lanes."""
         afters: dict[int, object] = {}
         with self._state_lock:
-            for d, blk in blocks.items():
-                table, after, health = step(self._tables[d], blk)
-                self._tables[d] = table
-                afters[d] = after
-                self._health.park(health)
+            with host_span("ratelimit.shard.launch"):
+                for d, blk in blocks.items():
+                    table, after, health = slab_step_routed(
+                        self._tables[d], blk, ways=self.ways, cap=cap,
+                        use_pallas=self._use_pallas,
+                    )
+                    self._tables[d] = table
+                    afters[d] = after
+                    self._health.park(health)
             self._note_routing_locked(
                 counts,
                 sum(blk.shape[1] for blk in blocks.values()),
@@ -763,22 +811,31 @@ class ShardedSlabEngine:
     def collect_after_compact(self, token) -> np.ndarray:
         """Blocking half: drain the sharded result and unscatter it back to
         arrival order using the routing permutation built at launch."""
-        if isinstance(token, dict):  # routed-arm token
-            return self._collect_routed(token)
+        with host_span("ratelimit.shard.collect"):
+            t0 = time.perf_counter_ns()
+            if isinstance(token, dict):  # routed-arm token
+                out, launched = self._collect_routed(token)
+            else:
+                out, launched = self._collect_compact(token)
+            if launched and self._h_collect is not None:
+                self._h_collect.record((time.perf_counter_ns() - t0) / 1e6)
+        return out
+
+    def _collect_compact(self, token):
         after_blocks, routed_idx, routed_owner, within, b, hot_remap = token
         out = np.zeros(b, dtype=np.uint32)
         if after_blocks is None:  # launch saw no valid lanes
-            return out
+            return out, False
         after_np = np.asarray(after_blocks)
         out[routed_idx] = after_np[routed_owner, within].astype(np.uint32)
         self._remap_hot(out, hot_remap)
-        return out
+        return out, True
 
-    def _collect_routed(self, token) -> np.ndarray:
+    def _collect_routed(self, token):
         out = np.zeros(token["b"], dtype=np.uint32)
         afters = token["afters"]
         if afters is None:  # launch saw no valid lanes
-            return out
+            return out, False
         routed_idx = token["routed_idx"]
         starts = token["starts"]
         counts = token["counts"]
@@ -787,7 +844,7 @@ class ShardedSlabEngine:
             after_np = np.asarray(after)[:c].astype(np.uint32)
             out[routed_idx[starts[d] : starts[d] + c]] = after_np
         self._remap_hot(out, token["hot_remap"])
-        return out
+        return out, True
 
     @staticmethod
     def _remap_hot(out: np.ndarray, hot_remap) -> None:
@@ -1077,6 +1134,9 @@ class ShardedSlabEngine:
         self._t_bucket_ns.append(t1 - t0)
         self._t_pad_ns.append(t2 - t1)
         self._t_launch_ns.append(t3 - t2)
+        if self._h_rows is not None:
+            self._h_rows.record(n_rows)
+            self._h_lanes.record(int(padded_lanes))
 
     def shard_routing_snapshot(self) -> dict:
         """Cumulative routing mix + stage-split percentiles — the source

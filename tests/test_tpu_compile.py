@@ -155,6 +155,26 @@ def test_routed_shard_step(topo):
     _check(compiled, pallas=True)
 
 
+@pytest.mark.parametrize("bucket", [16384, 32768])
+def test_routed_shard_step_is_named(topo, bucket):
+    """The program the routed engine launches per shard (slab_step_routed)
+    at incrby_mesh4's shard size, 2^24 slots, and the rungs its 65,536-row
+    launches pad to: its module is jit_slab_step_routed, the name the
+    benchmark's trace readers find it by."""
+    from jax.sharding import SingleDeviceSharding
+
+    from api_ratelimit_tpu.ops.slab import ROW_WIDTH
+    from api_ratelimit_tpu.parallel.sharded_slab import slab_step_routed
+
+    last = SingleDeviceSharding(topo.devices[3])
+    compiled = slab_step_routed.lower(
+        _u32(((1 << 26) // 4, ROW_WIDTH), last), _u32((7, bucket), last),
+        ways=WAYS, cap=0xFF, use_pallas=True,
+    ).compile()
+    _check(compiled, pallas=True)
+    assert compiled.as_text().startswith("HloModule jit_slab_step_routed")
+
+
 def test_health_fold(one_chip):
     """The health drain's one on-device program: the first n of
     HEALTH_FOLD parked vectors summed, whatever the drain's length."""
